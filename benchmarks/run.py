@@ -28,6 +28,9 @@ def main() -> None:
     args = ap.parse_args()
     scale = "full" if args.full else "quick"
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (chaos_bench, churn_bench, dynamic_speedup, memory_table,
                    pagerank_bench, serve_bench, sharded_bench, sweep_bench,
                    traversal, triangle_bench, update_bench,

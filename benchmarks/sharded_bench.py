@@ -36,12 +36,14 @@ Comparisons on an 8-virtual-device host mesh (the same
   1-shard/vmap/shard_map, PageRank bit-identical between dispatch modes
   (vs 1-shard: allclose — the per-shard sweep regroups the f32 sums).
 
-XLA locks the device count at first init, so ``run()`` re-execs this module
-in a subprocess with the forced-device env (benchmarks.run stays usable
-in-process).  Absolute times on a host-platform mesh are NOT a model of TPU
-all-to-all cost — the 8 virtual devices serialize on the host cores, so
-every ratio here is a lower bound on real-mesh scaling: the ratios track
-engine-vs-legacy work, not the wire.
+XLA locks the device count at first init, so on a CPU-pinned host
+(``JAX_PLATFORMS=cpu``) ``run()`` re-execs this module in a subprocess with
+the forced-device env (benchmarks.run stays usable in-process); on an
+accelerator host it runs in-process on the real devices.  Absolute times
+on a host-platform mesh are NOT a model of TPU all-to-all cost — the 8
+virtual devices serialize on the host cores, so every ratio here is a
+lower bound on real-mesh scaling: the ratios track engine-vs-legacy work,
+not the wire.
 """
 from __future__ import annotations
 
@@ -57,7 +59,14 @@ _OUT = pathlib.Path(__file__).resolve().parent.parent / "BENCH_sharded.json"
 
 
 def run(scale: str = "quick"):
-    """benchmarks.run entry point: re-exec with the 8-device env."""
+    """benchmarks.run entry point.  Where the environment pins JAX to the
+    CPU (``JAX_PLATFORMS=cpu``, decided before JAX is touched) re-exec with
+    8 forced host devices, since XLA fixes the device count at first use;
+    on an accelerator host run in-process on the real devices: a child
+    process could not reach a chip this process holds."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        _main(scale)
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
@@ -72,7 +81,6 @@ def _main(scale: str):
     import numpy as np
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     import dataclasses
 
@@ -90,6 +98,7 @@ def _main(scale: str):
                                                  route_edges, route_exchange,
                                                  routing_cap_blocks,
                                                  shard_from_edges_host,
+                                                 shard_mesh,
                                                  wcc_sharded)
     from repro.stream import GraphStore, ShardedGraphStore
     from repro.stream.sharded_store import _cap_rung
@@ -97,6 +106,9 @@ def _main(scale: str):
     from .timing import row
 
     S = min(8, len(jax.devices()))
+    if S < 2:
+        raise RuntimeError(f"the sharded bench needs at least 2 devices; "
+                           f"{jax.default_backend()} has {S}")
     # streams run at a bulk-update scale (the regime the single-program
     # plane is for); "full" additionally grows the graph
     V, E, bs, rounds = ((1 << 15, 240000, 8192, 3) if scale == "quick"
@@ -106,7 +118,7 @@ def _main(scale: str):
     src, dst = rmat_edges(V, E, seed=33)
     E = len(src)
 
-    mesh = jax.make_mesh((S,), (SHARD_AXIS,))
+    mesh = shard_mesh(S)
 
     def copy_sg(sg):
         return dataclasses.replace(
@@ -219,9 +231,7 @@ def _main(scale: str):
             st = ShardedGraphStore.from_edges(V, n_shards, src, dst,
                                               dispatch=dispatch)
             if dispatch != "vmap":
-                st.place_on_mesh(
-                    jax.make_mesh((n_shards,), (SHARD_AXIS,),
-                                  devices=jax.devices()[:n_shards]))
+                st.place_on_mesh(shard_mesh(n_shards))
             return st
         return make
 
@@ -291,9 +301,9 @@ def _main(scale: str):
         return jnp.concatenate(outs)[None]
 
     def probe_time(fn, n=10):
-        f = jax.jit(shard_map(fn, mesh=mesh, in_specs=(vec,) * 4,
+        f = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(vec,) * 4,
                               out_specs=P(SHARD_AXIS, None),
-                              check_rep=False))
+                              check_vma=False))
         jax.block_until_ready(f(*probe_args))
         t0 = time.perf_counter()
         for _ in range(n):

@@ -75,7 +75,8 @@ def bfs_vanilla(g: SlabGraph, *, src: int, edge_capacity: int,
 
 def bfs_tree_static(g: SlabGraph, src: int, *, edge_capacity: int,
                     max_bpv: int = 1,
-                    g_in: Optional[SlabGraph] = None
+                    g_in: Optional[SlabGraph] = None,
+                    rows: Optional[int] = None
                     ) -> Tuple[TreeState, jnp.ndarray]:
     """TREE-BASED static BFS: SSSP engine, unit weights (64-bit pair updates
     on GPU; two-plane lexicographic segment-min here)."""
@@ -83,24 +84,25 @@ def bfs_tree_static(g: SlabGraph, src: int, *, edge_capacity: int,
     improved0 = jnp.zeros((g.n_vertices,), bool).at[src].set(True)
     return run_to_convergence(g, state, improved0,
                               edge_capacity=edge_capacity, max_bpv=max_bpv,
-                              g_in=g_in)
+                              g_in=g_in, rows=rows)
 
 
 def bfs_incremental(g: SlabGraph, state: TreeState, bsrc, bdst, bmask, *,
-                    edge_capacity: int, max_bpv: int = 1, g_in=None):
+                    edge_capacity: int, max_bpv: int = 1, g_in=None,
+                    rows: Optional[int] = None):
     """Unit-weight incremental update via the SSSP engine."""
     bw = jnp.ones_like(bsrc, jnp.float32)
     return sssp_incremental(g, state, bsrc, bdst, bw, bmask,
                             edge_capacity=edge_capacity, max_bpv=max_bpv,
-                            g_in=g_in)
+                            g_in=g_in, rows=rows)
 
 
 def bfs_decremental(g: SlabGraph, state: TreeState, bsrc, bdst, bmask, *,
                     src: int, edge_capacity: int, max_bpv: int = 1,
-                    g_in=None):
+                    g_in=None, rows: Optional[int] = None):
     return sssp_decremental(g, state, bsrc, bdst, bmask, src=src,
                             edge_capacity=edge_capacity, max_bpv=max_bpv,
-                            g_in=g_in)
+                            g_in=g_in, rows=rows)
 
 
 # ---------------------------------------------------------------------------
@@ -124,7 +126,8 @@ def stream_property(src: int, *, edge_capacity: int, max_bpv: int = 1):
             "register sssp.stream_property on weighted stores"
         state, _ = bfs_tree_static(store.forward, src,
                                    edge_capacity=edge_capacity,
-                                   max_bpv=max_bpv, g_in=store.transpose)
+                                   max_bpv=max_bpv, g_in=store.transpose,
+                                   rows=store.sweep_rows())
         return state
 
     def _on_batch(store, state, batch):
@@ -132,15 +135,20 @@ def stream_property(src: int, *, edge_capacity: int, max_bpv: int = 1):
             state, _ = bfs_decremental(store.forward, state, batch.del_src,
                                        batch.del_dst, batch.del_mask, src=src,
                                        edge_capacity=edge_capacity,
-                                       max_bpv=max_bpv, g_in=store.transpose)
+                                       max_bpv=max_bpv, g_in=store.transpose,
+                                       rows=store.sweep_rows())
         if batch.ins_src is not None:
             state, _ = bfs_incremental(store.forward, state, batch.ins_src,
                                        batch.ins_dst, batch.ins_mask,
                                        edge_capacity=edge_capacity,
-                                       max_bpv=max_bpv, g_in=store.transpose)
+                                       max_bpv=max_bpv, g_in=store.transpose,
+                                       rows=store.sweep_rows())
         return state
 
+    # a deleting epoch's catch-up runs two convergence loops (decremental,
+    # then incremental) where a refresh runs one: replay one epoch at most
     return PropertySpec(
         name=f"bfs_{src}", init=_init, on_batch=_on_batch, refresh=_init,
+        max_replay=1,
         state_like=lambda n: TreeState(jnp.zeros((n,), jnp.float32),
                                        jnp.zeros((n,), jnp.int32)))

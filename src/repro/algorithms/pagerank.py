@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ..core.hashing import SLAB_WIDTH
 from ..core.slab_graph import SlabGraph
 from ..core.worklist import pool_edges
+from ..kernels.slab_sweep.ops import slice_rows
 
 
 def slab_contrib_sums_ref(keys: jnp.ndarray, valid: jnp.ndarray,
@@ -41,20 +42,24 @@ def slab_contrib_sums_ref(keys: jnp.ndarray, valid: jnp.ndarray,
     return jnp.sum(vals, axis=1)
 
 
-@partial(jax.jit, static_argnames=("damping", "max_iter", "contrib_impl"))
+@partial(jax.jit, static_argnames=("damping", "max_iter", "contrib_impl",
+                                   "rows"))
 def pagerank(g_in: SlabGraph, out_degree: jnp.ndarray, *,
              init_pr: Optional[jnp.ndarray] = None,
              damping: float = 0.85, error_margin: float = 1e-5,
              max_iter: int = 100,
-             contrib_impl: str = "ref") -> Tuple[jnp.ndarray, jnp.ndarray]:
+             contrib_impl: str = "ref",
+             rows: Optional[int] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Static (init_pr=None) or dynamic (init_pr=warm start) PageRank.
 
     Returns (pagerank vector, iterations).  ``contrib_impl`` selects the pool
     sweep implementation: "ref" is the in-module jnp oracle; "sweep" (alias
     "pallas") is the shared slab-sweep engine's sum semiring — the kernel
     under ``kernels/slab_sweep`` of which the historical ``slab_pagerank``
-    kernel is the specialization.
+    kernel is the specialization.  ``rows`` (static) bounds the sweep to the
+    allocated slab prefix (``GraphStore.sweep_rows``; bit-identical).
     """
+    g_in = slice_rows(g_in, rows)
     n = g_in.n_vertices
     view = pool_edges(g_in)
     seg = jnp.where(g_in.slab_vertex >= 0, g_in.slab_vertex, n)
@@ -126,7 +131,8 @@ def stream_property(*, damping: float = 0.85, error_margin: float = 1e-5,
                              "view; build the store with with_transpose=True")
         pr, _ = pagerank(store.transpose, store.out_degree, init_pr=init_pr,
                          damping=damping, error_margin=error_margin,
-                         max_iter=max_iter, contrib_impl=contrib_impl)
+                         max_iter=max_iter, contrib_impl=contrib_impl,
+                         rows=store.sweep_rows())
         return pr
 
     return PropertySpec(

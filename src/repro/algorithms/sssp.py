@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from ..core.slab_graph import SlabGraph
 from ..core.worklist import expand_vertices, pool_edges
-from ..kernels.slab_sweep.ops import sweep_vertices
+from ..kernels.slab_sweep.ops import slice_rows, sweep_vertices
 
 INF = jnp.float32(1e30)
 NO_PARENT = jnp.int32(-1)
@@ -107,11 +107,13 @@ def _compact_vertices(improved: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, 
     return verts, vmask, cnt
 
 
-@partial(jax.jit, static_argnames=("edge_capacity", "max_bpv", "max_iters"))
+@partial(jax.jit, static_argnames=("edge_capacity", "max_bpv", "max_iters",
+                                   "rows"))
 def run_to_convergence(g: SlabGraph, state: TreeState, improved0: jnp.ndarray,
                        *, edge_capacity: int, max_bpv: int = 1,
                        max_iters: int = 100000,
-                       g_in: Optional[SlabGraph] = None
+                       g_in: Optional[SlabGraph] = None,
+                       rows: Optional[int] = None
                        ) -> Tuple[TreeState, jnp.ndarray]:
     """Common epilogue (Alg. 6 lines 22–27): relax the improved frontier,
     repeat until it empties.  Returns (state, iterations).
@@ -120,8 +122,12 @@ def run_to_convergence(g: SlabGraph, state: TreeState, improved0: jnp.ndarray,
     loop is one fused slab sweep per plane — the improved mask IS the
     frontier bitmask, no vertex compaction, no EdgeFrontier.  Without it,
     the expand_vertices reference path runs (also the fallback when only
-    the out-edge view exists, e.g. mid-update-stream).
+    the out-edge view exists, e.g. mid-update-stream).  ``rows`` (static)
+    bounds the sweeps to ``g_in``'s allocated slab prefix
+    (``GraphStore.sweep_rows``; bit-identical to the full pool).
     """
+    if g_in is not None:
+        g_in = slice_rows(g_in, rows)
 
     def cond(carry):
         _, improved, it = carry
@@ -154,25 +160,27 @@ def run_to_convergence(g: SlabGraph, state: TreeState, improved0: jnp.ndarray,
 
 def sssp_static(g: SlabGraph, src: int, *, edge_capacity: int,
                 max_bpv: int = 1,
-                g_in: Optional[SlabGraph] = None
+                g_in: Optional[SlabGraph] = None,
+                rows: Optional[int] = None
                 ) -> Tuple[TreeState, jnp.ndarray]:
     """Alg. 6 lines 1–9: seed with the source's out-edges, iterate."""
     state = init_state(g.n_vertices, src)
     improved0 = jnp.zeros((g.n_vertices,), bool).at[src].set(True)
     return run_to_convergence(g, state, improved0,
                               edge_capacity=edge_capacity, max_bpv=max_bpv,
-                              g_in=g_in)
+                              g_in=g_in, rows=rows)
 
 
 # ---------------------------------------------------------------------------
 # incremental
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("edge_capacity", "max_bpv"))
+@partial(jax.jit, static_argnames=("edge_capacity", "max_bpv", "rows"))
 def sssp_incremental(g: SlabGraph, state: TreeState, bsrc: jnp.ndarray,
                      bdst: jnp.ndarray, bw: jnp.ndarray, bmask: jnp.ndarray,
                      *, edge_capacity: int, max_bpv: int = 1,
-                     g_in: Optional[SlabGraph] = None
+                     g_in: Optional[SlabGraph] = None,
+                     rows: Optional[int] = None
                      ) -> Tuple[TreeState, jnp.ndarray]:
     """Incremental prologue (Alg. 6 lines 12–14): the inserted batch IS the
     initial edge frontier (genuinely an edge list — it stays on
@@ -181,7 +189,7 @@ def sssp_incremental(g: SlabGraph, state: TreeState, bsrc: jnp.ndarray,
     state, improved = relax_edges(state, bsrc, bdst, bw, bmask)
     return run_to_convergence(g, state, improved,
                               edge_capacity=edge_capacity, max_bpv=max_bpv,
-                              g_in=g_in)
+                              g_in=g_in, rows=rows)
 
 
 # ---------------------------------------------------------------------------
@@ -224,12 +232,13 @@ def _propagate_invalidation(state: TreeState, src: int,
 
 
 @partial(jax.jit, static_argnames=("src", "edge_capacity", "max_bpv",
-                                   "n_rounds"))
+                                   "n_rounds", "rows"))
 def sssp_decremental(g: SlabGraph, state: TreeState, bsrc: jnp.ndarray,
                      bdst: jnp.ndarray, bmask: jnp.ndarray, *, src: int,
                      edge_capacity: int, max_bpv: int = 1,
                      n_rounds: int = 32,
-                     g_in: Optional[SlabGraph] = None
+                     g_in: Optional[SlabGraph] = None,
+                     rows: Optional[int] = None
                      ) -> Tuple[TreeState, jnp.ndarray]:
     """Decremental prologue (Alg. 6 lines 16–20) + common epilogue.
 
@@ -240,6 +249,24 @@ def sssp_decremental(g: SlabGraph, state: TreeState, bsrc: jnp.ndarray,
     """
     state = _invalidate(state, bsrc, bdst, bmask)
     state = _propagate_invalidation(state, src, n_rounds)
+    alive = state.dist < INF
+
+    if g_in is not None:
+        g_in = slice_rows(g_in, rows)
+        # the same re-seeding relaxation as one frontier-masked sweep
+        # (frontier = surviving sources, result kept at invalidated
+        # targets): no pool-sized edge list, which at Graph500 scale 21
+        # would not fit next to the views in a 16 GB v5e
+        dmin = sweep_vertices(g_in, state.dist, semiring="min_plus",
+                              frontier=alive)
+        pmin = sweep_vertices(g_in, state.dist, semiring="arg_min_plus",
+                              frontier=alive, target=dmin)
+        state, improved = _apply_relax(
+            state, jnp.where(alive, INF, dmin),
+            jnp.where(alive, jnp.int32(2 ** 31 - 1), pmin))
+        return run_to_convergence(g, state, improved,
+                                  edge_capacity=edge_capacity,
+                                  max_bpv=max_bpv, g_in=g_in)
 
     view = pool_edges(g)
     fsrc = view.src.reshape(-1)
@@ -247,7 +274,6 @@ def sssp_decremental(g: SlabGraph, state: TreeState, bsrc: jnp.ndarray,
     fw = (view.weight.reshape(-1) if g.weighted
           else jnp.ones_like(fsrc, jnp.float32))
     fvalid = view.valid.reshape(-1)
-    alive = state.dist < INF
     d_clip = jnp.where(fvalid, fdst.astype(jnp.int32), 0)
     s_clip = jnp.where(fvalid, fsrc, 0)
     emask = fvalid & alive[s_clip] & ~alive[d_clip]
@@ -273,7 +299,7 @@ def stream_property(src: int, *, edge_capacity: int, max_bpv: int = 1,
     def _init(store):
         state, _ = sssp_static(store.forward, src,
                                edge_capacity=edge_capacity, max_bpv=max_bpv,
-                               g_in=store.transpose)
+                               g_in=store.transpose, rows=store.sweep_rows())
         return state
 
     def _on_batch(store, state, batch):
@@ -282,17 +308,22 @@ def stream_property(src: int, *, edge_capacity: int, max_bpv: int = 1,
                                         batch.del_dst, batch.del_mask,
                                         src=src, edge_capacity=edge_capacity,
                                         max_bpv=max_bpv, n_rounds=n_rounds,
-                                        g_in=store.transpose)
+                                        g_in=store.transpose,
+                                        rows=store.sweep_rows())
         if batch.ins_src is not None:
             w = (batch.ins_w if batch.ins_w is not None
                  else jnp.ones_like(batch.ins_src, jnp.float32))
             state, _ = sssp_incremental(store.forward, state, batch.ins_src,
                                         batch.ins_dst, w, batch.ins_mask,
                                         edge_capacity=edge_capacity,
-                                        max_bpv=max_bpv, g_in=store.transpose)
+                                        max_bpv=max_bpv, g_in=store.transpose,
+                                        rows=store.sweep_rows())
         return state
 
+    # a deleting epoch's catch-up runs two convergence loops (decremental,
+    # then incremental) where a refresh runs one: replay one epoch at most
     return PropertySpec(
         name=f"sssp_{src}", init=_init, on_batch=_on_batch, refresh=_init,
+        max_replay=1,
         state_like=lambda n: TreeState(jnp.zeros((n,), jnp.float32),
                                        jnp.zeros((n,), jnp.int32)))

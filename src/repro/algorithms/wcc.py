@@ -217,6 +217,7 @@ def stream_property(*, cap: int | None = None):
                                            batch.ins_dst, batch.ins_mask)
         return labels
 
+    # a deleting epoch's catch-up IS a refresh: replay one epoch at most
     return PropertySpec(
         name="wcc", init=_refresh, on_batch=_on_batch, refresh=_refresh,
-        state_like=lambda n: jnp.zeros((n,), jnp.int32))
+        max_replay=1, state_like=lambda n: jnp.zeros((n,), jnp.int32))

@@ -236,6 +236,17 @@ def from_edges_host(n_vertices: int, src: np.ndarray, dst: np.ndarray,
     throughput); this numpy path exists so large test graphs construct fast.
     Duplicate (src,dst) pairs are dropped, matching insert semantics.
     """
+    return jax.tree.map(jnp.asarray, from_edges_numpy(
+        n_vertices, src, dst, weights, load_factor=load_factor,
+        hashing=hashing, slack_slabs=slack_slabs))
+
+
+def from_edges_numpy(n_vertices: int, src: np.ndarray, dst: np.ndarray,
+                     weights: Optional[np.ndarray] = None, *,
+                     load_factor: float = 0.7, hashing: bool = True,
+                     slack_slabs: int = 0) -> SlabGraph:
+    """``from_edges_host`` with numpy leaves, still in host memory — for
+    callers that place the pools on devices themselves."""
     src = np.asarray(src, dtype=np.uint32)
     dst = np.asarray(dst, dtype=np.uint32)
     w = None if weights is None else np.asarray(weights, dtype=np.float32)
@@ -322,26 +333,26 @@ def from_edges_host(n_vertices: int, src: np.ndarray, dst: np.ndarray,
                          0).astype(np.int32)
 
     return SlabGraph(
-        keys=jnp.asarray(keys),
-        weights=None if wpool is None else jnp.asarray(wpool),
-        next_slab=jnp.asarray(nxt),
-        slab_vertex=jnp.asarray(slab_vertex),
-        bucket_offset=jnp.asarray(bucket_offset.astype(np.int32)),
-        bucket_count=jnp.asarray(bucket_count),
-        bucket_vertex=jnp.asarray(bucket_vertex),
-        tail_slab=jnp.asarray(tail_slab),
-        tail_fill=jnp.asarray(tail_fill),
-        upd_flag=jnp.zeros(n_buckets, dtype=bool),
-        upd_slab=jnp.asarray(tail_slab),
-        upd_lane=jnp.asarray(tail_fill),
-        next_free=jnp.asarray(total_slabs, dtype=jnp.int32),
-        epoch_next_free=jnp.asarray(total_slabs, dtype=jnp.int32),
-        free_list=jnp.full((capacity,), -1, dtype=jnp.int32),
-        free_top=jnp.asarray(0, dtype=jnp.int32),
-        slab_new=jnp.zeros((capacity,), dtype=bool),
-        degree=jnp.asarray(np.bincount(src.astype(np.int64),
-                                       minlength=n_vertices).astype(np.int32)),
-        n_edges=jnp.asarray(len(src), dtype=jnp.int32),
+        keys=keys,
+        weights=wpool,
+        next_slab=nxt,
+        slab_vertex=slab_vertex,
+        bucket_offset=bucket_offset.astype(np.int32),
+        bucket_count=bucket_count,
+        bucket_vertex=bucket_vertex,
+        tail_slab=tail_slab,
+        tail_fill=tail_fill,
+        upd_flag=np.zeros(n_buckets, dtype=bool),
+        upd_slab=tail_slab,
+        upd_lane=tail_fill,
+        next_free=np.asarray(total_slabs, dtype=np.int32),
+        epoch_next_free=np.asarray(total_slabs, dtype=np.int32),
+        free_list=np.full((capacity,), -1, dtype=np.int32),
+        free_top=np.asarray(0, dtype=np.int32),
+        slab_new=np.zeros((capacity,), dtype=bool),
+        degree=np.bincount(src.astype(np.int64),
+                           minlength=n_vertices).astype(np.int32),
+        n_edges=np.asarray(len(src), dtype=np.int32),
         n_vertices=n_vertices,
         n_buckets=n_buckets,
         weighted=w is not None,

@@ -17,15 +17,17 @@ def rmat_edges(n_vertices: int, n_edges: int, *, seed: int = 0,
     scale = int(np.ceil(np.log2(max(n_vertices, 2))))
     src = np.zeros(n_edges, dtype=np.int64)
     dst = np.zeros(n_edges, dtype=np.int64)
+    # one quadrant per level: src bit from r, dst bit from r2 conditioned
+    # on it (in place: Graph500 scales are tens of millions of edges)
     for level in range(scale):
-        r = rng.random(n_edges)
-        src_bit = (r >= a + b).astype(np.int64)
+        src_bit = rng.random(n_edges) >= a + b
         r2 = rng.random(n_edges)
-        dst_bit = np.where(src_bit == 0,
-                           (r >= a).astype(np.int64) * 0 + (r2 >= a / (a + b)).astype(np.int64),
-                           (r2 >= c / (c + (1 - a - b - c) + 1e-12)).astype(np.int64))
-        src = src * 2 + src_bit
-        dst = dst * 2 + dst_bit
+        dst_bit = np.where(src_bit, r2 >= c / (c + (1 - a - b - c) + 1e-12),
+                           r2 >= a / (a + b))
+        src <<= 1
+        src |= src_bit
+        dst <<= 1
+        dst |= dst_bit
     src %= n_vertices
     dst %= n_vertices
     keep = src != dst

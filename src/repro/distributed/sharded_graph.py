@@ -35,8 +35,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import batch as B
 from ..core import slab_graph as SG
@@ -71,6 +70,20 @@ def graph_pspecs(graphs: SG.SlabGraph):
         lambda x: P(*((SHARD_AXIS,) + (None,) * (x.ndim - 1))), graphs)
 
 
+def shard_mesh(n_shards: int) -> Mesh:
+    """The 1-D ``("shard",)`` mesh over the first ``n_shards`` devices, one
+    shard per device.  Raises when the backend has fewer devices: shards
+    are never stacked onto one device behind the caller's back."""
+    devices = jax.devices()
+    if len(devices) < n_shards:
+        raise ValueError(
+            f"{n_shards} shards need {n_shards} devices; the "
+            f"{jax.default_backend()} backend has {len(devices)}")
+    return jax.make_mesh((n_shards,), (SHARD_AXIS,),
+                         axis_types=(AxisType.Auto,),
+                         devices=devices[:n_shards])
+
+
 def place_on_mesh(sg: ShardedSlabGraph, mesh: Mesh) -> ShardedSlabGraph:
     """Pin every stacked pool leaf under ``NamedSharding(P("shard", ...))``
     so per-shard state lives on its device for its whole lifetime
@@ -80,6 +93,12 @@ def place_on_mesh(sg: ShardedSlabGraph, mesh: Mesh) -> ShardedSlabGraph:
     if tuple(mesh.axis_names) != (SHARD_AXIS,):
         raise ValueError(f"expected a ('{SHARD_AXIS}',) mesh, got axes "
                          f"{tuple(mesh.axis_names)}")
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        # the plane's vmapped stacked-shard paths (query, vmap dispatch,
+        # maintenance) mix mesh-placed pools with unplaced batches, which
+        # only Auto (compiler-propagated) sharding accepts
+        raise ValueError(f"expected an Auto-typed mesh (see shard_mesh), "
+                         f"got axis types {mesh.axis_types}")
     if mesh.devices.size != sg.n_shards:
         raise ValueError(f"mesh has {mesh.devices.size} devices for "
                          f"{sg.n_shards} shards (need exactly one each)")
@@ -119,35 +138,37 @@ def shard_slice(sg: ShardedSlabGraph, k: int) -> SG.SlabGraph:
 
 
 def _grow_to(g: SG.SlabGraph, capacity: int) -> SG.SlabGraph:
-    """Pad one shard's pools to an exact row count (stacking needs uniform
-    shapes; unlike ``ensure_capacity`` this targets a capacity, not slack)."""
+    """Pad one shard's host (numpy) pools to an exact row count (stacking
+    needs uniform shapes; unlike ``ensure_capacity`` this targets a
+    capacity, not slack)."""
     grow = capacity - g.capacity_slabs
     if grow <= 0:
         return g
 
-    def pad_rows(a, fill, dtype):
-        pad = jnp.full((grow,) + a.shape[1:], fill, dtype=dtype)
-        return jnp.concatenate([a, pad], axis=0)
+    def pad_rows(a, fill):
+        pad = np.full((grow,) + a.shape[1:], fill, dtype=a.dtype)
+        return np.concatenate([a, pad], axis=0)
 
     return dataclasses.replace(
         g,
-        keys=pad_rows(g.keys, EMPTY_KEY, jnp.uint32),
-        weights=(pad_rows(g.weights, 0.0, jnp.float32)
-                 if g.weighted else None),
-        next_slab=pad_rows(g.next_slab, INVALID_SLAB, jnp.int32),
-        slab_vertex=pad_rows(g.slab_vertex, -1, jnp.int32),
-        free_list=pad_rows(g.free_list, INVALID_SLAB, jnp.int32),
-        slab_new=pad_rows(g.slab_new, False, bool),
+        keys=pad_rows(g.keys, EMPTY_KEY),
+        weights=pad_rows(g.weights, 0.0) if g.weighted else None,
+        next_slab=pad_rows(g.next_slab, INVALID_SLAB),
+        slab_vertex=pad_rows(g.slab_vertex, -1),
+        free_list=pad_rows(g.free_list, INVALID_SLAB),
+        slab_new=pad_rows(g.slab_new, False),
     )
 
 
 def shard_from_edges_host(n_vertices_global: int, n_shards: int, src, dst,
-                          weights=None, *, slack_slabs: int = 0
-                          ) -> ShardedSlabGraph:
+                          weights=None, *, slack_slabs: int = 0,
+                          mesh: Optional[Mesh] = None) -> ShardedSlabGraph:
     """Host-side bulk construction of the sharded graph (the compact
     ``from_edges_host`` analogue): partition edges by owner, build each
     shard's local pool densely (single-bucket mode, local src / GLOBAL dst
-    keys), pad every pool to one common pow2 capacity, stack.
+    keys), pad every pool to one common pow2 capacity, stack — all in host
+    memory — then copy to the device, or with ``mesh`` straight to each
+    shard's device (``place_on_mesh``).
 
     Semantically identical to routing the edges through
     ``insert_edges_sharded`` on ``shard_empty`` — without the engine's
@@ -162,15 +183,18 @@ def shard_from_edges_host(n_vertices_global: int, n_shards: int, src, dst,
     shards = []
     for k in range(n_shards):
         m = (src % np.uint32(n_shards)) == k
-        shards.append(SG.from_edges_host(
+        shards.append(SG.from_edges_numpy(
             n_local, src[m] // np.uint32(n_shards), dst[m],
             None if w is None else w[m],
             hashing=False, slack_slabs=slack_slabs))
     cap = next_pow2(max(g.capacity_slabs for g in shards))
     shards = [_grow_to(g, cap) for g in shards]
-    graphs = jax.tree.map(lambda *xs: jnp.stack(xs), *shards)
-    return ShardedSlabGraph(graphs=graphs, n_shards=n_shards,
-                            n_vertices_global=n_vertices_global)
+    graphs = jax.tree.map(lambda *xs: np.stack(xs), *shards)
+    sg = ShardedSlabGraph(graphs=graphs, n_shards=n_shards,
+                          n_vertices_global=n_vertices_global)
+    if mesh is not None:
+        return place_on_mesh(sg, mesh)
+    return dataclasses.replace(sg, graphs=jax.tree.map(jnp.asarray, graphs))
 
 
 def owner_of(v: jnp.ndarray, n_shards: int) -> jnp.ndarray:
@@ -642,11 +666,11 @@ def _run_sharded_fix(sg: ShardedSlabGraph, dispatch, rows, fix_of, consts):
         # strided slice this shard owns (+ its copy of the iter counter)
         return out[0][_local_slice_idx(V, S, me)][None], out[2][None]
 
-    res_loc, iters = shard_map(
+    res_loc, iters = jax.shard_map(
         body_shard, mesh=sg.mesh,
         in_specs=(graph_pspecs(sg.graphs),) + tuple(P() for _ in consts),
         out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS)),
-        check_rep=False)(sg.graphs, *consts)
+        check_vma=False)(sg.graphs, *consts)
     return reassemble_global(res_loc, V), iters[0]
 
 

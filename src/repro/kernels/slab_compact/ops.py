@@ -26,14 +26,14 @@ maintenance is what keeps long streams flat; this module is that plane:
 Implementation selection (``impl``) mirrors the update engine:
 
 * ``"pallas"`` — tiled census + per-tile-terminating chain-rank kernels
-  (``kernel.py``; compiled on TPU, interpret elsewhere — validation, not
-  speed);
-* ``"jnp"``    — the same scan-based plan lowered through XLA (fast path
-  off-TPU): per-lane destinations from live-prefix ranks, NO whole-pool
-  lane sort;
+  (``kernel.py``), interpret mode only (for validation): Mosaic does not
+  lower the census's ``cumsum`` nor the chain rank's ``dynamic_slice``, so
+  the TPU v5e compiler refuses both;
+* ``"jnp"``    — the same scan-based plan lowered through XLA: per-lane
+  destinations from live-prefix ranks, NO whole-pool lane sort;
 * ``"oracle"`` — the sort-based whole-pool rebuild (``ref.py``), bit-exact
   reference;
-* ``"auto"``   — ``"pallas"`` on TPU, ``"jnp"`` otherwise.
+* ``"auto"``   — ``"jnp"`` on every backend (``repro.kernels.resolve_impl``).
 
 All three produce leaf-for-leaf identical graphs and permutations
 (tests/test_maintenance.py).  Compaction must run on a CLOSED epoch (the
@@ -53,6 +53,7 @@ import numpy as np
 from ...core.hashing import EMPTY_KEY, INVALID_SLAB, SLAB_WIDTH
 from ...core.slab_graph import SlabGraph, next_pow2
 from ...obs import timed_dispatch
+from .. import resolve_impl
 from .kernel import chain_rank_pallas, slab_live_pallas
 from .ref import (assemble, chain_order, compact_ref, live_lane_mask,
                   perm_of, rebuild_links, recount_degrees, slab_of_rank)
@@ -61,14 +62,8 @@ IMPLS = ("auto", "pallas", "jnp", "oracle")
 
 
 def _resolve(impl: str, interpret: Optional[bool]):
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "auto":
-        impl = "pallas" if on_tpu else "jnp"
-    if impl not in ("pallas", "jnp", "oracle"):
-        raise ValueError(f"unknown impl {impl!r}")
-    if interpret is None:
-        interpret = not on_tpu
-    return impl, interpret
+    return resolve_impl(impl, interpret, xla="jnp",
+                        impls=("pallas", "jnp", "oracle"))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -15,7 +15,11 @@ Engines for ``count_edges`` (Σ_edges |N_G1(u) ∩ N_G2(v)|):
   the traced program stays O(1) in SLAB_WIDTH/lane_chunk instead of
   unrolling, and each chunk's probe is a single fused bucket chain-walk.
 * ``pallas`` — ``kernel.slab_count_pallas``: tiled work items with per-tile
-  termination at both the G2 walk and the G1 probe (interpret mode off-TPU).
+  termination at both the G2 walk and the G1 probe.  Interpret mode only:
+  the TPU v5e compiler refuses its ``keys_ref[idx]`` gather (``Cannot do
+  int indexing on TPU``).
+
+``impl="auto"`` is ``jnp`` on every backend (``repro.kernels.resolve_impl``).
 
 All three are bit-identical on the count (the sum is order-independent);
 tests/test_triangle_stream.py holds them to the oracle per impl.
@@ -32,6 +36,7 @@ from ...core.batch import edge_buckets, probe
 from ...core.hashing import INVALID_SLAB, SLAB_WIDTH, is_valid_vertex
 from ...core.slab_graph import SlabGraph
 from ...obs import timed_dispatch
+from .. import resolve_impl
 from .kernel import probe_hits_pallas, slab_count_pallas
 from .ref import count_edges_ref, probe_hits_ref, search_edges_ref
 
@@ -41,14 +46,8 @@ _STATIC = ("impl", "interpret", "max_bpv", "lane_chunk", "edges_per_tile")
 
 
 def _resolve(impl: str, interpret: Optional[bool]):
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "auto":
-        impl = "pallas" if on_tpu else "jnp"
-    if impl not in ("pallas", "jnp", "oracle"):
-        raise ValueError(f"unknown impl {impl!r}")
-    if interpret is None:
-        interpret = not on_tpu
-    return impl, interpret
+    return resolve_impl(impl, interpret, xla="jnp",
+                        impls=("pallas", "jnp", "oracle"))
 
 
 def _work_items(g2: SlabGraph, us, vs, emask, *, max_bpv: int):
@@ -208,11 +207,13 @@ def materialize_chains(g: SlabGraph, us: jnp.ndarray, ws: jnp.ndarray,
 def search_edges_kernel(g: SlabGraph, us: jnp.ndarray, ws: jnp.ndarray,
                         mask: jnp.ndarray, *, max_chain: int = 8,
                         impl: str = "auto") -> jnp.ndarray:
-    """Drop-in for ``algorithms.triangle.search_edges`` using the kernel."""
+    """Drop-in for ``algorithms.triangle.search_edges`` over materialized
+    chains (``impl``: ``"auto"`` = ``"ref"``, or ``"pallas"``)."""
+    impl, interpret = resolve_impl(impl, None, xla="ref",
+                                   impls=("pallas", "ref"))
     rows = materialize_chains(g, us, ws, mask, max_chain=max_chain)
     if impl == "ref":
         return probe_hits_ref(ws, rows, g.keys) & mask
-    interpret = jax.default_backend() != "tpu"
     return probe_hits_pallas(ws, rows, g.keys, interpret=interpret) & mask
 
 

@@ -8,13 +8,14 @@ label propagation (min), and SSSP/BFS relaxation (min-plus / arg-min-plus).
 
 Implementation selection (``impl``):
 
-  * ``"pallas"`` — the fused Pallas kernel (compiled on TPU; interpret mode
-    elsewhere unless overridden — the interpreter is for validation, not
-    speed).
-  * ``"ref"``    — the pure-jnp oracle, itself a single fused XLA
-    gather+reduce (the fast path off-TPU: still no ``EdgeFrontier``
-    materialization, no cumsum+scatter compaction).
-  * ``"auto"``   — ``"pallas"`` on TPU, ``"ref"`` otherwise.
+  * ``"pallas"`` — the fused Pallas kernel, interpret mode only (for
+    validation): the TPU v5e compiler refuses its ``values_ref[idx]``
+    gather (``Cannot do int indexing on TPU``), so a compiled call on a TPU
+    raises that error.
+  * ``"ref"``    — the pure-jnp engine, itself a single fused XLA
+    gather+reduce (no ``EdgeFrontier`` materialization, no cumsum+scatter
+    compaction).
+  * ``"auto"``   — ``"ref"`` on every backend (``repro.kernels.resolve_impl``).
 
 Both implementations are lane-for-lane identical (integer/min semirings
 bit-exact; sums share the same lane-axis reduction order).
@@ -29,14 +30,15 @@ import jax.numpy as jnp
 
 from ...core.slab_graph import SlabGraph
 from ...obs import timed_dispatch
+from .. import resolve_impl
 from .kernel import slab_sweep_pallas
 from .ref import SEMIRINGS, slab_sweep_ref
 
 _MIN_FAMILY = ("min", "min_plus", "arg_min_plus")
 
 
-def _slice_rows(g: SlabGraph, rows: Optional[int],
-                rows_per_block: int) -> SlabGraph:
+def slice_rows(g: SlabGraph, rows: Optional[int],
+               rows_per_block: int = 256) -> SlabGraph:
     """Statically bound the sweep to the first ``rows`` pool rows.
 
     ``rows`` is a host-known upper bound on the allocated region (max
@@ -59,14 +61,8 @@ def _slice_rows(g: SlabGraph, rows: Optional[int],
 
 
 def _resolve(impl: str, interpret: Optional[bool]):
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "auto":
-        impl = "pallas" if on_tpu else "ref"
-    if impl not in ("pallas", "ref"):
-        raise ValueError(f"unknown impl {impl!r}")
-    if interpret is None:
-        interpret = not on_tpu
-    return impl, interpret
+    return resolve_impl(impl, interpret, xla="ref",
+                        impls=("pallas", "ref"))
 
 
 @timed_dispatch("slab_sweep")
@@ -90,7 +86,7 @@ def sweep_partials(g: SlabGraph, values: jnp.ndarray, *, semiring: str,
     it passes the global vertex count here (``values``/``frontier`` are
     then global vectors while the owner axis stays shard-local).
     ``rows`` (static) bounds the sweep to the allocated pool prefix —
-    see ``_slice_rows``; results are bit-identical to the full sweep.
+    see ``slice_rows``; results are bit-identical to the full sweep.
     This entry point is shard_map-compatible: called on a shard-local
     ``SlabGraph`` block inside a ``shard_map`` body it traces per-shard
     collective-free code (the sharded plane composes it with
@@ -98,7 +94,7 @@ def sweep_partials(g: SlabGraph, values: jnp.ndarray, *, semiring: str,
     """
     if semiring not in SEMIRINGS:
         raise ValueError(f"unknown semiring {semiring!r}")
-    g = _slice_rows(g, rows, rows_per_block)
+    g = slice_rows(g, rows, rows_per_block)
     if weighted is None:
         weighted = g.weighted and semiring in ("min_plus", "arg_min_plus")
     weights = g.weights if weighted else None
@@ -138,7 +134,7 @@ def sweep_vertices(g: SlabGraph, values: jnp.ndarray, *, semiring: str,
     sliced-out rows contribute only semiring identities); shard_map-safe
     like ``sweep_partials``.
     """
-    g = _slice_rows(g, rows, rows_per_block)
+    g = slice_rows(g, rows, rows_per_block)
     partials = sweep_partials(g, values, semiring=semiring, frontier=frontier,
                               target=target, weighted=weighted, n_keys=n_keys,
                               impl=impl, rows_per_block=rows_per_block,
@@ -149,5 +145,5 @@ def sweep_vertices(g: SlabGraph, values: jnp.ndarray, *, semiring: str,
     return reduce(partials, seg, num_segments=g.n_vertices + 1)[:g.n_vertices]
 
 
-__all__ = ["sweep_partials", "sweep_vertices", "slab_sweep_pallas",
-           "slab_sweep_ref", "SEMIRINGS"]
+__all__ = ["sweep_partials", "sweep_vertices", "slice_rows",
+           "slab_sweep_pallas", "slab_sweep_ref", "SEMIRINGS"]

@@ -30,13 +30,14 @@ bit-for-bit:
 
 Implementation selection (``impl``):
 
-* ``"pallas"`` — probe/commit Pallas kernels (compiled on TPU; interpret
-  mode elsewhere — validation, not speed);
-* ``"jnp"``    — the run-local engine lowered through XLA scatters (the
-  fast path off-TPU);
+* ``"pallas"`` — probe/commit Pallas kernels, interpret mode only (for
+  validation): the TPU v5e compiler refuses the probe's ``keys_ref[idx]``
+  gather from an ``ANY`` ref (``Cannot do int indexing on TPU``) and the
+  commit kernel's loads outside VMEM/SMEM;
+* ``"jnp"``    — the run-local engine lowered through XLA scatters;
 * ``"oracle"`` — the original whole-pool path (``ref.py``), bit-exact
   reference;
-* ``"auto"``   — ``"pallas"`` on TPU, ``"jnp"`` otherwise.
+* ``"auto"``   — ``"jnp"`` on every backend (``repro.kernels.resolve_impl``).
 
 All three produce bit-identical graphs and masks (tests/test_slab_update.py).
 """
@@ -52,6 +53,7 @@ from ...core.hashing import (INVALID_SLAB, INVALID_VERTEX, SLAB_WIDTH,
                              TOMBSTONE_KEY)
 from ...core.slab_graph import SlabGraph
 from ...obs import timed_dispatch
+from .. import resolve_impl
 from .kernel import slab_commit_pallas, slab_probe_pallas
 from .ref import (batch_valid, delete_edges_ref, edge_buckets,
                   insert_edges_ref, probe, query_edges_ref)
@@ -89,14 +91,8 @@ def _copy_aliased(tree):
 
 
 def _resolve(impl: str, interpret: Optional[bool]):
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "auto":
-        impl = "pallas" if on_tpu else "jnp"
-    if impl not in ("pallas", "jnp", "oracle"):
-        raise ValueError(f"unknown impl {impl!r}")
-    if interpret is None:
-        interpret = not on_tpu
-    return impl, interpret
+    return resolve_impl(impl, interpret, xla="jnp",
+                        impls=("pallas", "jnp", "oracle"))
 
 
 def _probe_dispatch(g, bucket, dst, valid, *, impl, interpret, qpt):

@@ -22,45 +22,166 @@ import time
 import numpy as np
 
 
-def build_requests(n_vertices, initial_edges, rng, *, n_requests: int,
+_EMPTY = np.uint64(0xFFFF_FFFF_FFFF_FFFF)   # never a key: src < 2^32 - 1
+_DEAD = np.uint64(0xFFFF_FFFF_FFFF_FFFE)    # removed; probing passes it
+
+
+def edge_keys(src, dst) -> np.ndarray:
+    """(src, dst) pairs as ``src << 32 | dst`` uint64 keys."""
+    return ((np.asarray(src).astype(np.uint64) << np.uint64(32))
+            | np.asarray(dst).astype(np.uint64))
+
+
+class EdgeLedger:
+    """The workload generator's exact set of live edges (not graph state:
+    the store owns the graph), with per-request work bounded by the batch.
+
+    Keys live in a dense array, so deletes sample uniform indices and
+    remove by swapping tail entries into the holes.  A linear-probing hash
+    set of the same keys answers "already present?" for inserts.  Removed
+    slots stay as markers that probes pass and inserts reuse; the set is
+    rebuilt from the live keys, doubling as needed, once live keys plus
+    markers would fill half of it.  ``capacity`` pre-sizes both for that
+    many live edges.
+    """
+
+    def __init__(self, src, dst, *, capacity: int = 0):
+        self._keys = np.empty(max(int(capacity), len(src), 1), np.uint64)
+        self._n = 0
+        self._rehash()
+        self.add(src, dst)
+
+    def __len__(self) -> int:
+        return self._n
+
+    def edges(self):
+        """(src, dst) uint32 copies of the live edges."""
+        return _split(self._keys[:self._n])
+
+    def _rehash(self) -> None:
+        bits = max(2 * len(self._keys) - 1, 1).bit_length()
+        self._table = np.full(1 << bits, _EMPTY, np.uint64)
+        self._shift = np.uint64(64 - bits)
+        self._used = 0                       # bound on non-empty slots
+        self._place(self._keys[:self._n])
+
+    def _home(self, keys):
+        # Fibonacci hashing: the top bits of key * 2^64/phi
+        return ((keys * np.uint64(0x9E37_79B9_7F4A_7C15)) >> self._shift
+                ).astype(np.int64)
+
+    def _slots(self, keys) -> np.ndarray:
+        """Table slot of each key, -1 where absent."""
+        mask = len(self._table) - 1
+        pos = self._home(keys)
+        out = np.full(len(keys), -1, np.int64)
+        todo = np.arange(len(keys))
+        while todo.size:
+            t = self._table[pos[todo]]
+            hit = t == keys[todo]
+            out[todo[hit]] = pos[todo[hit]]
+            todo = todo[~hit & (t != _EMPTY)]
+            pos[todo] = (pos[todo] + 1) & mask
+        return out
+
+    def _place(self, keys) -> None:
+        """Put distinct absent ``keys`` into the hash set."""
+        mask = len(self._table) - 1
+        pos = self._home(keys)
+        todo = np.arange(len(keys))
+        while todo.size:
+            p = pos[todo]
+            free = np.isin(self._table[p], (_EMPTY, _DEAD))
+            # every claimant writes, one write per slot survives: the keys
+            # read back are the round's winners
+            self._table[p[free]] = keys[todo[free]]
+            won = free & (self._table[p] == keys[todo])
+            todo = todo[~won]
+            pos[todo] = (pos[todo] + 1) & mask
+        self._used += len(keys)
+
+    def contains(self, src, dst) -> np.ndarray:
+        return self._slots(edge_keys(src, dst)) >= 0
+
+    def add(self, src, dst) -> int:
+        """Insert-set semantics: adds the pairs not already live; returns
+        how many were added."""
+        keys = np.unique(edge_keys(src, dst))
+        keys = keys[self._slots(keys) < 0]
+        n = self._n + len(keys)
+        if n > len(self._keys):
+            grown = np.empty(max(n, 2 * len(self._keys)), np.uint64)
+            grown[:self._n] = self._keys[:self._n]
+            self._keys = grown
+            self._rehash()
+        elif 2 * (self._used + len(keys)) > len(self._table):
+            self._rehash()
+        self._place(keys)
+        self._keys[self._n:n] = keys
+        self._n = n
+        return len(keys)
+
+    def sample(self, k: int, rng):
+        """Up to ``k`` distinct live edges, uniformly, as (src, dst)."""
+        idx = rng.choice(self._n, min(k, self._n), replace=False)
+        return _split(self._keys[idx])
+
+    def take(self, k: int, rng):
+        """``sample`` and remove: the deletes of one update request."""
+        idx = np.sort(rng.choice(self._n, min(k, self._n), replace=False))
+        keys = self._keys[idx]
+        self._table[self._slots(keys)] = _DEAD
+        tail = self._n - len(idx)
+        holes = idx[idx < tail]
+        movers = np.setdiff1d(np.arange(tail, self._n), idx,
+                              assume_unique=True)
+        self._keys[holes] = self._keys[movers]
+        self._n = tail
+        return _split(keys)
+
+
+def _split(keys):
+    return ((keys >> np.uint64(32)).astype(np.uint32),
+            (keys & np.uint64(0xFFFF_FFFF)).astype(np.uint32))
+
+
+def update_request(ledger: EdgeLedger, rng, n_vertices: int, batch: int,
+                   delete_frac: float):
+    """One mixed ``UpdateBatch``: ``batch * delete_frac`` deletes of live
+    edges and uniform random inserts (self-loops dropped).  The ledger
+    follows the store's semantics — deletes first, then inserts."""
+    from ..stream import UpdateBatch
+
+    n_del = int(batch * delete_frac)
+    ins = rng.integers(0, n_vertices, (batch - n_del, 2)).astype(np.uint32)
+    ins = ins[ins[:, 0] != ins[:, 1]]
+    del_src, del_dst = ledger.take(n_del, rng)
+    ledger.add(ins[:, 0], ins[:, 1])
+    return UpdateBatch(ins_src=ins[:, 0], ins_dst=ins[:, 1],
+                       del_src=del_src, del_dst=del_dst)
+
+
+def build_requests(n_vertices, ledger: EdgeLedger, rng, *, n_requests: int,
                    batch: int, delete_frac: float, prop_names):
     """Synthesize the request mix, one generator step per served request.
 
-    Deletions are sampled from a host-side ledger of currently-present edges
-    (the workload generator's bookkeeping, not graph state — the store owns
-    the graph; ``initial_edges`` is the deduped (src, dst) pair list it was
-    built from, so the same generator drives sharded and unsharded stores).
+    Deletions are sampled from ``ledger``, the generator's set of live
+    edges, so the same generator drives sharded and unsharded stores.
     Yields (kind, request) pairs lazily so each update samples from the
     post-update ledger.
     """
-    from ..stream import MembershipQuery, PropertyRead, UpdateBatch
+    from ..stream import MembershipQuery, PropertyRead
 
-    src0, dst0 = initial_edges
-    present = set(zip(np.asarray(src0).tolist(),
-                      np.asarray(dst0).astype(np.int64).tolist()))
     kinds = ["update"] + [f"read:{p}" for p in prop_names] + ["member"]
-    V = n_vertices
-
     for i in range(n_requests):
         kind = kinds[i % len(kinds)]
         if kind == "update":
-            n_del = int(batch * delete_frac)
-            n_ins = batch - n_del
-            ins = rng.integers(0, V, (n_ins, 2)).astype(np.uint32)
-            ins = ins[ins[:, 0] != ins[:, 1]]
-            pool = np.array(sorted(present), np.uint32) if present else \
-                np.zeros((0, 2), np.uint32)
-            dels = pool[rng.choice(len(pool), min(n_del, len(pool)),
-                                   replace=False)] if len(pool) else pool
-            present -= {(int(s), int(d)) for s, d in dels}
-            present |= {(int(s), int(d)) for s, d in ins}
-            yield kind, UpdateBatch(ins_src=ins[:, 0], ins_dst=ins[:, 1],
-                                    del_src=dels[:, 0] if len(dels) else (),
-                                    del_dst=dels[:, 1] if len(dels) else ())
+            yield kind, update_request(ledger, rng, n_vertices, batch,
+                                       delete_frac)
         elif kind.startswith("read:"):
             yield kind, PropertyRead(kind.split(":", 1)[1])
         else:
-            q = rng.integers(0, V, (1024, 2)).astype(np.uint32)
+            q = rng.integers(0, n_vertices, (1024, 2)).astype(np.uint32)
             yield kind, MembershipQuery(src=q[:, 0], dst=q[:, 1])
 
 
@@ -83,6 +204,63 @@ def describe(resp, n_vertices: int) -> str:
     return ""
 
 
+def build_service(n_vertices: int, src, dst, *, shards: int = 1,
+                  insert_budget: int, policy: str = "lazy",
+                  maintain: bool = True, tombstone_ratio: float = 0.2,
+                  health=None):
+    """The served path, built once for ``serve`` and ``chip_smoke.py``:
+    store → ``PropertyRegistry`` (PageRank, BFS from vertex 0, WCC under
+    ``policy``) → ``RequestPipeline``.  Returns ``(store, registry,
+    pipeline)``.
+
+    ``src``/``dst`` is the deduped initial edge list and ``insert_budget``
+    bounds how many edges the run inserts: it sizes the pool slack and the
+    edge lists of BFS re-seeding and WCC unions, which must hold every live
+    edge.  ``shards > 1`` builds a ``ShardedGraphStore`` placed on a
+    ``("shard",)`` mesh of the first ``shards`` devices; with fewer
+    devices it raises.  ``maintain`` attaches a ``MaintenancePolicy``
+    (slab compaction + free-slab recycling at epoch close).
+    """
+    from ..algorithms import (bfs_stream_property, pagerank_stream_property,
+                              wcc_stream_property)
+    from ..distributed.sharded_graph import shard_mesh
+    from ..stream import (GraphStore, MaintenancePolicy, PropertyRegistry,
+                          RequestPipeline, ShardedGraphStore,
+                          sharded_bfs_property, sharded_pagerank_property,
+                          sharded_wcc_property)
+
+    maintenance = (MaintenancePolicy(tombstone_ratio=tombstone_ratio)
+                   if maintain else None)
+    if shards > 1:
+        # sharded serving plane: same views, vertex-partitioned, one shard
+        # per device; the analytics run as distributed slab-sweep
+        # super-steps
+        store = ShardedGraphStore.from_edges(
+            n_vertices, shards, src, dst, maintenance=maintenance,
+            mesh=shard_mesh(shards))
+        registry = PropertyRegistry(store)
+        registry.register(sharded_pagerank_property(), policy=policy)
+        registry.register(sharded_bfs_property(0), policy=policy)
+        registry.register(sharded_wcc_property(), policy=policy)
+    else:
+        # pagerank/bfs/wcc read only the forward + transpose views; skip the
+        # symmetric one rather than pay its maintenance every epoch
+        store = GraphStore.from_edges(
+            n_vertices, src, dst, hashing=False, with_symmetric=False,
+            slack_slabs=insert_budget // 64 + 512, maintenance=maintenance)
+        registry = PropertyRegistry(store)
+        cap = len(src) + insert_budget + 4096
+        registry.register(pagerank_stream_property(), policy=policy)
+        registry.register(bfs_stream_property(0, edge_capacity=cap),
+                          policy=policy)
+        # the default cap (every pool lane) needs 13 GiB of temporaries at
+        # Graph500 scale 21; the live edge bound needs 6
+        registry.register(wcc_stream_property(cap=cap), policy=policy)
+    pipeline = RequestPipeline(store, registry, health=health,
+                               health_every=8)
+    return store, registry, pipeline
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--vertices", type=int, default=20000)
@@ -99,8 +277,9 @@ def main():
     ap.add_argument("--tombstone-ratio", type=float, default=0.2,
                     help="compaction trigger: dead/occupied lanes")
     ap.add_argument("--shards", type=int, default=1,
-                    help="vertex-partition the store across N shards "
-                         "(ShardedGraphStore; N>1 wants N devices or "
+                    help="vertex-partition the store across N shards, one "
+                         "per device of a ('shard',) mesh (ShardedGraphStore; "
+                         "N>1 needs N devices — on a CPU host "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     ap.add_argument("--checkpoint", default=None,
                     help="directory to snapshot the store into at the end")
@@ -127,6 +306,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from .. import obs
     if args.trace or args.metrics or args.metrics_json:
         # tracing and metrics arm together here: the trace export appends
@@ -175,44 +356,13 @@ def main():
 
         signal.signal(signal.SIGTERM, _on_sigterm)
 
-    from ..algorithms import (bfs_stream_property, pagerank_stream_property,
-                              wcc_stream_property)
     from ..data.synth import rmat_edges
-    from ..stream import (GraphStore, MaintenancePolicy, PropertyRegistry,
-                          RequestPipeline, ShardedGraphStore,
-                          sharded_bfs_property, sharded_pagerank_property,
-                          sharded_wcc_property)
+    from ..stream import dedup_pairs
 
     rng = np.random.default_rng(args.seed)
     V = args.vertices
     src, dst = rmat_edges(V, args.initial_edges, seed=args.seed)
-    from ..stream import dedup_pairs
     src, dst, _ = dedup_pairs(src, dst)
-    policy = (MaintenancePolicy(tombstone_ratio=args.tombstone_ratio)
-              if args.maintain else None)
-    if args.shards > 1:
-        # sharded serving plane: same views, vertex-partitioned; the
-        # analytics run as distributed slab-sweep super-steps
-        store = ShardedGraphStore.from_edges(V, args.shards, src, dst,
-                                             maintenance=policy)
-        registry = PropertyRegistry(store)
-        registry.register(sharded_pagerank_property(), policy=args.policy)
-        registry.register(sharded_bfs_property(0), policy=args.policy)
-        registry.register(sharded_wcc_property(), policy=args.policy)
-    else:
-        # pagerank/bfs/wcc read only the forward + transpose views; skip the
-        # symmetric one rather than pay its maintenance every epoch
-        store = GraphStore.from_edges(
-            V, src, dst, hashing=False, with_symmetric=False,
-            slack_slabs=args.requests * args.batch // 64 + 512,
-            maintenance=policy)
-        registry = PropertyRegistry(store)
-        cap = len(src) + args.requests * args.batch + 4096
-        registry.register(pagerank_stream_property(), policy=args.policy)
-        registry.register(bfs_stream_property(0, edge_capacity=cap),
-                          policy=args.policy)
-        registry.register(wcc_stream_property(), policy=args.policy)
-    print(f"[serve] boot: V={V} E={store.n_edges} shards={args.shards}")
     health = None
     if args.health:
         from ..obs.health import HealthEngine, SLOTarget
@@ -222,15 +372,20 @@ def main():
              SLOTarget("property", latency_s=4 * slo_s, objective=0.9),
              SLOTarget("member", latency_s=slo_s, objective=0.9)],
             window=128)
-    pipeline = RequestPipeline(store, registry, health=health,
-                               health_every=8)
+    budget = args.requests * args.batch
+    store, registry, pipeline = build_service(
+        V, src, dst, shards=args.shards, insert_budget=budget,
+        policy=args.policy, maintain=args.maintain,
+        tombstone_ratio=args.tombstone_ratio, health=health)
+    print(f"[serve] boot: V={V} E={store.n_edges} shards={args.shards}")
 
     # per-request-class latency histograms (standalone — always collected,
     # the flag-free Histogram class costs one record per request); the
     # update class is the apply path, everything else is query-side
     lat = {}
     t0 = time.time()
-    stream = build_requests(V, (src, dst), rng, n_requests=args.requests,
+    ledger = EdgeLedger(src, dst, capacity=len(src) + budget)
+    stream = build_requests(V, ledger, rng, n_requests=args.requests,
                             batch=args.batch, delete_frac=args.delete_frac,
                             prop_names=["pagerank", "bfs_0", "wcc"])
     for i, (kind, req) in enumerate(stream):

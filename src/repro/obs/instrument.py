@@ -37,13 +37,31 @@ import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
+from jax.core import Tracer
+
 from . import flight, metrics, trace
 
-try:                                    # jax >= 0.4: real trace-state probe
-    from jax.core import trace_state_clean as _trace_state_clean
-except ImportError:                     # pragma: no cover - version fallback
-    def _trace_state_clean() -> bool:
-        return True
+
+_PLAIN = frozenset((int, float, bool, str, type(None)))
+
+
+def _traced(args, kwargs) -> bool:
+    """True when any argument leaf is a tracer: the call is being traced
+    (jit / shard_map / vmap / while_loop body), not dispatched.  Checked
+    per argument, flattening only pytree containers (SlabGraph, tuples of
+    views): this runs on every dispatch of the always-on flight path."""
+    for a in (args if not kwargs else (*args, *kwargs.values())):
+        if type(a) in _PLAIN:
+            continue
+        if isinstance(a, Tracer):
+            return True
+        if isinstance(a, jax.Array):
+            continue
+        if any(isinstance(x, Tracer) for x in jax.tree_util.tree_leaves(a)):
+            return True
+    return False
+
 
 _tls = threading.local()
 _lock = threading.Lock()
@@ -53,7 +71,6 @@ _KERNEL_STATS: Dict[Tuple[str, str, str], Dict[str, float]] = {}
 
 
 def _arrays(tree):
-    import jax
     return [x for x in jax.tree_util.tree_leaves(tree)
             if isinstance(x, jax.Array)]
 
@@ -147,7 +164,7 @@ def timed_dispatch(family: str, op: Optional[str] = None,
             if not (metrics.enabled() or trace.enabled()
                     or flight.enabled()):
                 return fn(*args, **kwargs)
-            if getattr(_tls, "depth", 0) > 0 or not _trace_state_clean():
+            if getattr(_tls, "depth", 0) > 0 or _traced(args, kwargs):
                 return fn(*args, **kwargs)
             if not (metrics.enabled() or trace.enabled()):
                 # flight-only (the always-on default): one ring write per

@@ -9,8 +9,9 @@ keyed to GraphStore versions.  Two maintenance policies:
   state; it is cleared when the epoch closes).
 * ``lazy``  — invalidation only: the state is caught up on first read by
   replaying the store's batch log through ``on_batch``; if the bounded log has
-  been truncated past the property's version, ``refresh`` (static recompute)
-  runs instead.  Queries only pay for the properties they read.
+  been truncated past the property's version, or the property lags by more
+  epochs than its ``max_replay``, ``refresh`` (static recompute) runs
+  instead.  Queries only pay for the properties they read.
 
 ``state_like(n_vertices)`` builds a cheap structural skeleton of the state
 pytree so checkpoints restore without recomputing anything.
@@ -36,6 +37,11 @@ class PropertySpec:
     ``collapse_replay`` declares ``on_batch`` batch-independent (it only
     reads the current graph, e.g. warm-started PageRank): lazy catch-up
     then runs it ONCE instead of once per missed epoch.
+
+    ``max_replay`` bounds lazy catch-up: a property that lags by more
+    (non-maintenance) epochs than this is refreshed instead of replayed.
+    Set it where replaying one epoch costs about as much as ``refresh``
+    (None = always replay while the log covers the lag).
     """
     name: str
     init: Callable[[GraphStore], Any]
@@ -43,6 +49,7 @@ class PropertySpec:
     refresh: Callable[[GraphStore], Any]
     state_like: Optional[Callable[[int], Any]] = None
     collapse_replay: bool = False
+    max_replay: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -119,8 +126,10 @@ class PropertyRegistry:
             # maintenance epochs are replay no-ops (edge set unchanged)
             missed = [b for b in missed if not b.maintenance]
         name = e.spec.name
-        if missed is None:
-            # log truncated past the property's version: static recompute
+        limit = e.spec.max_replay
+        if missed is None or (limit is not None and len(missed) > limit):
+            # log truncated past the property's version, or a replay
+            # dearer than one static recompute
             with obs.span("property.refresh", prop=name):
                 e.state = e.spec.refresh(self.store)
             obs.inc(f"property.{name}.refresh")

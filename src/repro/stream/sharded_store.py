@@ -48,7 +48,6 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import obs
@@ -323,13 +322,13 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
     def batch_specs(t):
         return jax.tree.map(lambda _: vec, t)
 
-    out_views, del_parts, ins_parts = shard_map(
+    out_views, del_parts, ins_parts = jax.shard_map(
         _body, mesh=mesh,
         in_specs=(gspecs, batch_specs(dels), batch_specs(ins)),
         out_specs=(gspecs,
                    None if dels is None else P(SHARD_AXIS, None),
                    None if ins is None else P(SHARD_AXIS, None)),
-        check_rep=False)(views, dels, ins)
+        check_vma=False)(views, dels, ins)
     # each batch position is owned by exactly one shard: OR the partials
     ins_mask = None if ins_parts is None else ins_parts.any(axis=0)
     del_mask = None if del_parts is None else del_parts.any(axis=0)
@@ -446,11 +445,14 @@ class ShardedGraphStore(VersionedStoreBase):
                    slack_slabs: int = 0,
                    log_capacity: int = 64,
                    maintenance=None,
-                   dispatch: str = "auto") -> "ShardedGraphStore":
+                   dispatch: str = "auto",
+                   mesh: Optional[Mesh] = None) -> "ShardedGraphStore":
         """Bulk-build every view host-side (``shard_from_edges_host`` —
-        dense pools, dedup shared; the engine path serves the epochs)."""
+        dense pools, dedup shared; the engine path serves the epochs).
+        With ``mesh`` each view's pools go straight to their shards'
+        devices, as after ``place_on_mesh``."""
         src, dst, w = dedup_pairs(src, dst, w)
-        kw = dict(slack_slabs=slack_slabs)
+        kw = dict(slack_slabs=slack_slabs, mesh=mesh)
         views = {FORWARD: shard_from_edges_host(
             n_vertices, n_shards, src, dst, w, **kw)}
         if with_transpose:
@@ -1036,9 +1038,10 @@ def sharded_wcc_property(*, max_iters: int = 100000):
             return _run(store)
         return _run(store, init_labels=labels)
 
+    # a deleting epoch's catch-up IS a refresh: replay one epoch at most
     return PropertySpec(
         name="wcc", init=_run, on_batch=_on_batch, refresh=_run,
-        state_like=lambda n: jnp.zeros((n,), jnp.int32))
+        max_replay=1, state_like=lambda n: jnp.zeros((n,), jnp.int32))
 
 
 def sharded_bfs_property(src: int, *, max_iters: int = 100000):
@@ -1064,8 +1067,10 @@ def sharded_bfs_property(src: int, *, max_iters: int = 100000):
             return _run(store)
         return _run(store, init_dist=dist)
 
+    # a deleting epoch's catch-up IS a refresh: replay one epoch at most
     return PropertySpec(
         name=f"bfs_{src}", init=_run, on_batch=_on_batch, refresh=_run,
+        max_replay=1,
         state_like=lambda n: jnp.zeros((n,), jnp.int32))
 
 

@@ -517,6 +517,20 @@ class GraphStore(VersionedStoreBase):
     def max_bpv(self) -> int:
         return self._max_bpv
 
+    def sweep_rows(self, view: str = TRANSPOSE) -> Optional[int]:
+        """Static sweep row bound for the analytics (``rows=``), or None
+        without that view: the allocated prefix (``next_free``) rounded up
+        to a sixteenth of the pool, so sweeps skip the pow2 capacity slack
+        while a growing pool recompiles them at most 16 times per
+        capacity.  Rows past ``next_free`` hold no edges: results are
+        bit-identical to a full-pool sweep."""
+        g = self._views.get(view)
+        if g is None:
+            return None
+        cap = g.capacity_slabs
+        step = max(256, cap // 16)
+        return min(cap, -(-int(g.next_free) // step) * step)
+
     # ----------------------------------------------------------------- apply
     def apply(self, ins_src=None, ins_dst=None, ins_w=None,
               del_src=None, del_dst=None) -> AppliedBatch:

@@ -13,7 +13,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 assert len(jax.devices()) == 8, jax.devices()
 
@@ -26,7 +26,7 @@ from repro.launch.steps import build_lm_train_step, lm_param_specs, lm_opt_specs
 from repro.models import transformer as tfm
 from repro.train import optimizer as opt
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = get_arch("gemma2-9b").smoke_config()
 key = jax.random.PRNGKey(0)
 params = tfm.init_params(cfg, key)
@@ -76,7 +76,7 @@ src, dst = src[keep], dst[keep]
 
 sg = shard_empty(V, S, capacity_slabs_per_shard=256)
 # place every shard's arrays across the 8 devices (leading dim = shard)
-flat_mesh = jax.make_mesh((8,), ("shard",))
+flat_mesh = jax.make_mesh((8,), ("shard",), axis_types=(AxisType.Auto,))
 def place(x):
     if x.ndim == 0:
         return x
@@ -206,11 +206,13 @@ from repro.checkpoint import ckpt
 
 with tempfile.TemporaryDirectory() as td:
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = jax.make_mesh((4, 2), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
     placed = jax.device_put(tree["w"],
                             NamedSharding(mesh_a, P("data", "model")))
     ckpt.save(td, 1, {"w": placed})
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_b = jax.make_mesh((2, 4), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
     shardings = {"w": NamedSharding(mesh_b, P("model", "data"))}
     restored, _ = ckpt.restore(td, tree, shardings=shardings)
     assert np.array_equal(np.asarray(restored["w"]), np.asarray(tree["w"]))

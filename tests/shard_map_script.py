@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharded_graph import shard_mesh
 from repro.stream import GraphStore, ShardedGraphStore
 
 assert len(jax.devices()) == 8, jax.devices()
@@ -32,7 +33,7 @@ src = rng.integers(0, V, 400).astype(np.uint32)
 dst = rng.integers(0, V, 400).astype(np.uint32)
 keep = src != dst
 src, dst = src[keep], dst[keep]
-mesh = jax.make_mesh((S,), ("shard",))
+mesh = shard_mesh(S)
 
 sv = ShardedGraphStore.from_edges(V, S, src, dst, dispatch="vmap")
 sm = ShardedGraphStore.from_edges(V, S, src, dst).place_on_mesh(mesh)
@@ -164,6 +165,30 @@ assert np.array_equal(np.asarray(lab_v), np.asarray(lab_m))
 assert np.array_equal(np.asarray(lab_m), np.asarray(lab_1))
 print("OK analytics bit-identical between dispatch modes "
       "(pagerank also vs 1-shard at 1e-5)")
+
+# the served path as ``serve --shards 4`` builds it: a 4-shard store placed
+# on a 4-device mesh behind the request pipeline, every read checked against
+# the numpy reference over the live edge set (chip_smoke.py's CPU rehearsal)
+import contextlib
+import importlib.util
+import io
+
+spec = importlib.util.spec_from_file_location(
+    "chip_smoke", os.path.join(os.path.dirname(__file__), "..",
+                               "chip_smoke.py"))
+chip_smoke = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(chip_smoke)
+log = io.StringIO()
+with contextlib.redirect_stdout(log):
+    rc = chip_smoke.main(["--allow-cpu", "--chips", "4", "--scale", "11",
+                          "--batch", "1024"])
+lines = log.getvalue().splitlines()
+assert rc == 0, log.getvalue()
+for what in ("membership 5", "bfs levels", "wcc partition",
+             "forward placement", "symmetric placement"):
+    assert any(what in line and " ok " in line for line in lines), what
+print("OK served path: 4-shard store on a 4-device mesh answers membership, "
+      "BFS and WCC reads equal to the numpy reference")
 
 if os.environ.get("SHARD_MAP_PERF") == "1":
     # CI smoke gate: the sharded shard_map sweep must not lose to the

@@ -150,9 +150,10 @@ def test_search_edges_kernel_matches_algorithm():
     qd = rng.integers(0, n, 128).astype(np.uint32)
     mask = jnp.ones(128, bool)
     want = search_edges(g, jnp.asarray(qs), jnp.asarray(qd), mask)
-    got = search_edges_kernel(g, jnp.asarray(qs), jnp.asarray(qd), mask,
-                              max_chain=8)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for impl in ("auto", "pallas"):
+        got = search_edges_kernel(g, jnp.asarray(qs), jnp.asarray(qd), mask,
+                                  max_chain=8, impl=impl)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -214,3 +215,25 @@ def test_chunked_attention_grad_matches_ref():
     g2 = jax.grad(lambda q: attention_ref(q, k, v).sum())(q)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-4,
                                rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# implementation selection
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_auto_resolves_to_the_xla_engine(monkeypatch, backend):
+    """``impl="auto"`` never picks a Pallas kernel, on any backend: the
+    TPU v5e compiler refuses them, and the CPU tests must run the path the
+    chip runs."""
+    import jax
+    from repro.kernels.slab_compact.ops import _resolve as compact
+    from repro.kernels.slab_intersect.ops import _resolve as intersect
+    from repro.kernels.slab_sweep.ops import _resolve as sweep
+    from repro.kernels.slab_update.ops import _resolve as update
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert sweep("auto", None) == ("ref", backend != "tpu")
+    for resolve in (update, compact, intersect):
+        assert resolve("auto", None) == ("jnp", backend != "tpu")
+    # Pallas stays reachable by name: interpreted off-TPU, compiled on it
+    assert update("pallas", None) == ("pallas", backend != "tpu")
+

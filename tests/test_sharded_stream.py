@@ -349,9 +349,10 @@ class TestShardedStore:
 class TestShardMapDispatchS1:
     def test_epochs_and_analytics_identical_to_vmap(self):
         import jax
+        from repro.distributed.sharded_graph import shard_mesh
         rng = np.random.default_rng(11)
         src, dst = rand_edges(rng, 160)
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = shard_mesh(1)
         sv = ShardedGraphStore.from_edges(V, 1, src, dst, dispatch="vmap")
         sm = ShardedGraphStore.from_edges(V, 1, src, dst) \
             .place_on_mesh(mesh)
@@ -383,6 +384,16 @@ class TestShardMapDispatchS1:
                               np.asarray(reg_v.read("pagerank")))
         assert np.array_equal(np.asarray(reg_m.read("wcc")),
                               np.asarray(reg_v.read("wcc")))
+
+    def test_place_on_mesh_needs_auto_axes(self):
+        import jax
+        rng = np.random.default_rng(13)
+        src, dst = rand_edges(rng, 40)
+        st = ShardedGraphStore.from_edges(V, 1, src, dst)
+        explicit = jax.make_mesh((1,), ("shard",),
+                                 axis_types=(jax.sharding.AxisType.Explicit,))
+        with pytest.raises(ValueError, match="Auto-typed mesh"):
+            st.place_on_mesh(explicit)
 
     def test_dispatch_mode_validation(self):
         rng = np.random.default_rng(12)

@@ -12,8 +12,9 @@ from repro.algorithms import (bfs_stream_property, bfs_tree_static, pagerank,
                               wcc_stream_property)
 from repro.core import from_edges_host, pool_edges
 from repro.stream import (GraphStore, MembershipQuery, NeighborsQuery,
-                          PropertyRead, PropertyRegistry, RequestPipeline,
-                          UpdateBatch, coalesce_updates, dedup_pairs)
+                          PropertyRead, PropertyRegistry, PropertySpec,
+                          RequestPipeline, UpdateBatch, coalesce_updates,
+                          dedup_pairs)
 
 V = 24
 CAP = 4096
@@ -191,6 +192,48 @@ class TestProperties:
         assert status["wcc"]["stale"] and not status["bfs_0"]["stale"]
         registry.read("wcc")
         assert not registry.status()["wcc"]["stale"]
+
+    def test_lag_past_max_replay_refreshes(self):
+        """A lazy property replays a lag of up to ``max_replay`` epochs and
+        refreshes past it; BFS and WCC declare one."""
+        calls = []
+
+        def note(kind):
+            return lambda store, *_: calls.append(kind) or store.version
+
+        store = GraphStore.from_edges(V, [0, 1], [1, 2])
+        registry = PropertyRegistry(store)
+        registry.register(PropertySpec(
+            "version", init=note("init"), on_batch=note("replay"),
+            refresh=note("refresh"), max_replay=1), policy="lazy")
+        store.apply(ins_src=[2], ins_dst=[3])
+        assert registry.read("version") == store.version
+        for k in range(3):
+            store.apply(ins_src=[3 + k], ins_dst=[4 + k])
+        assert registry.read("version") == store.version
+        assert calls == ["init", "replay", "refresh"]
+        assert bfs_stream_property(0, edge_capacity=CAP).max_replay == 1
+        assert wcc_stream_property().max_replay == 1
+
+    def test_sweep_rows_bound_is_bit_identical(self):
+        """``GraphStore.sweep_rows`` cuts the sweeps to the allocated slab
+        prefix; PageRank and the BFS tree come out bit-identical."""
+        n = 600
+        rng = np.random.default_rng(3)
+        src, dst = rng.integers(0, n, (2, 3000)).astype(np.uint32)
+        keep = src != dst
+        store = GraphStore.from_edges(n, src[keep], dst[keep])
+        rows = store.sweep_rows()
+        assert rows < store.transpose.capacity_slabs
+        assert rows >= int(store.transpose.next_free)
+        for got, want in (
+                (pagerank(store.transpose, store.out_degree, rows=rows)[0],
+                 pagerank(store.transpose, store.out_degree)[0]),
+                *zip(bfs_tree_static(store.forward, 0, edge_capacity=CAP,
+                                     g_in=store.transpose, rows=rows)[0],
+                     bfs_tree_static(store.forward, 0, edge_capacity=CAP,
+                                     g_in=store.transpose)[0])):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
 
     def test_truncated_log_falls_back_to_refresh(self):
         store = GraphStore.from_edges(V, [0, 1], [1, 2], log_capacity=1)
