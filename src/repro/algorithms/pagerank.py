@@ -123,16 +123,22 @@ def stream_property(*, damping: float = 0.85, error_margin: float = 1e-5,
     """PropertySpec for the stream registry: PageRank over the store's
     transpose view with device-resident out-degrees; incremental ==
     decremental == warm start, so ``on_batch`` ignores the batch contents."""
+    from .. import obs
     from ..stream.properties import PropertySpec
 
     def _run(store, init_pr=None):
         if store.transpose is None:
             raise ValueError("pagerank stream property sweeps the transpose "
                              "view; build the store with with_transpose=True")
-        pr, _ = pagerank(store.transpose, store.out_degree, init_pr=init_pr,
-                         damping=damping, error_margin=error_margin,
-                         max_iter=max_iter, contrib_impl=contrib_impl,
-                         rows=store.sweep_rows())
+        with obs.span("pagerank.solve", warm=init_pr is not None) as sp:
+            pr, iters = pagerank(store.transpose, store.out_degree,
+                                 init_pr=init_pr, damping=damping,
+                                 error_margin=error_margin,
+                                 max_iter=max_iter,
+                                 contrib_impl=contrib_impl,
+                                 rows=store.sweep_rows())
+            obs.count("pagerank.iters", iters)
+            sp.sync_on(pr)
         return pr
 
     return PropertySpec(

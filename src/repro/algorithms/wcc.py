@@ -22,8 +22,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.slab_graph import SlabGraph
-from ..core.union_find import compress, init_parents, union_batch
+from ..core.union_find import (compress, init_parents, union_batch,
+                               union_batch_counted)
 from ..core.worklist import pool_edges, updated_lane_mask, updated_vertices
 from ..kernels.slab_sweep.ops import sweep_vertices
 
@@ -48,9 +50,10 @@ def _compact_lanes(g: SlabGraph, lane_mask: jnp.ndarray, cap: int):
 
 @partial(jax.jit, static_argnames=("cap",))
 def _union_pool(parent: jnp.ndarray, g: SlabGraph,
-                lane_mask: jnp.ndarray, *, cap: int) -> jnp.ndarray:
+                lane_mask: jnp.ndarray, *, cap: int):
+    """The union over the masked pool lanes: (parents, hooking rounds)."""
     u, v, m = _compact_lanes(g, lane_mask, cap)
-    return union_batch(parent, u, v, m)
+    return union_batch_counted(parent, u, v, m)
 
 
 def _edge_cap(g: SlabGraph) -> int:
@@ -58,19 +61,29 @@ def _edge_cap(g: SlabGraph) -> int:
     return g.capacity_slabs * SLAB_WIDTH
 
 
-def wcc_static(g: SlabGraph, *, cap: int | None = None) -> jnp.ndarray:
-    """Single traversal over all adjacencies; returns per-vertex labels."""
+def wcc_static_counted(g: SlabGraph, *, cap: int | None = None
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``wcc_static`` and the union's hooking rounds (an int32 scalar)."""
     parent = init_parents(g.n_vertices)
-    parent = _union_pool(parent, g, pool_edges(g).valid,
-                         cap=cap or _edge_cap(g))
-    return compress(parent)
+    parent, rounds = _union_pool(parent, g, pool_edges(g).valid,
+                                 cap=cap or _edge_cap(g))
+    return compress(parent), rounds
+
+
+def wcc_static(g: SlabGraph, *, cap: int | None = None) -> jnp.ndarray:
+    """Single traversal over all adjacencies; returns per-vertex labels.
+    With tracing on, the union's hooking rounds ride the caller's span as
+    ``wcc.hook_rounds``."""
+    labels, rounds = wcc_static_counted(g, cap=cap)
+    obs.count("wcc.hook_rounds", rounds)
+    return labels
 
 
 def wcc_incremental_naive(parent: jnp.ndarray, g: SlabGraph, *,
                           cap: int | None = None) -> jnp.ndarray:
     """Naive scheme: traverse every slab list (running time ∝ |E|)."""
     return compress(_union_pool(parent, g, pool_edges(g).valid,
-                                cap=cap or _edge_cap(g)))
+                                cap=cap or _edge_cap(g))[0])
 
 
 @partial(jax.jit, static_argnames=("cap", "max_bpv"))
@@ -207,14 +220,19 @@ def stream_property(*, cap: int | None = None):
     from ..stream.properties import PropertySpec
 
     def _refresh(store):
-        return wcc_static(store.forward, cap=cap)
+        with obs.span("wcc.refresh") as sp:
+            labels = wcc_static(store.forward, cap=cap)
+            sp.sync_on(labels)
+        return labels
 
     def _on_batch(store, labels, batch):
         if batch.n_deleted > 0:
             return _refresh(store)
         if batch.ins_src is not None:
-            labels = wcc_incremental_batch(labels, batch.ins_src,
-                                           batch.ins_dst, batch.ins_mask)
+            with obs.span("wcc.incremental") as sp:
+                labels = wcc_incremental_batch(labels, batch.ins_src,
+                                               batch.ins_dst, batch.ins_mask)
+                sp.sync_on(labels)
         return labels
 
     # a deleting epoch's catch-up IS a refresh: replay one epoch at most

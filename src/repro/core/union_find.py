@@ -39,19 +39,20 @@ def find(parent: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.jit
-def union_batch(parent: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
-                mask: jnp.ndarray) -> jnp.ndarray:
+def union_batch_counted(parent: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
+                        mask: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """UNION-ASYNC over an edge batch: hook max-root under min-root until no
     edge connects two distinct roots.  Deterministic: conflicting hooks on a
-    root resolve by scatter-min."""
+    root resolve by scatter-min.  Returns the parents and the number of
+    hooking rounds (an int32 scalar)."""
     parent = compress(parent)
 
     def cond(state):
-        parent, active = state
+        parent, active, _ = state
         return jnp.any(active)
 
     def body(state):
-        parent, active = state
+        parent, active, rounds = state
         ru = parent[jnp.where(mask, u, 0)]
         rv = parent[jnp.where(mask, v, 0)]
         differs = mask & (ru != rv) & active
@@ -62,12 +63,19 @@ def union_batch(parent: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
         parent = compress(parent)
         ru2 = parent[jnp.where(mask, u, 0)]
         rv2 = parent[jnp.where(mask, v, 0)]
-        return parent, mask & (ru2 != rv2)
+        return parent, mask & (ru2 != rv2), rounds + 1
 
     active0 = mask & (parent[jnp.where(mask, u, 0)] !=
                       parent[jnp.where(mask, v, 0)])
-    parent, _ = jax.lax.while_loop(cond, body, (parent, active0))
-    return parent
+    parent, _, rounds = jax.lax.while_loop(
+        cond, body, (parent, active0, jnp.zeros((), jnp.int32)))
+    return parent, rounds
+
+
+def union_batch(parent: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
+                mask: jnp.ndarray) -> jnp.ndarray:
+    """``union_batch_counted`` without the round count."""
+    return union_batch_counted(parent, u, v, mask)[0]
 
 
 def component_labels(parent: jnp.ndarray) -> jnp.ndarray:

@@ -52,11 +52,12 @@ import jax.numpy as jnp
 from ...core.hashing import (INVALID_SLAB, INVALID_VERTEX, SLAB_WIDTH,
                              TOMBSTONE_KEY)
 from ...core.slab_graph import SlabGraph
+from ... import obs
 from ...obs import timed_dispatch
 from .. import resolve_impl
 from .kernel import slab_commit_pallas, slab_probe_pallas
-from .ref import (batch_valid, delete_edges_ref, edge_buckets,
-                  insert_edges_ref, probe, query_edges_ref)
+from .ref import (ProbeCount, batch_valid, delete_edges_ref, edge_buckets,
+                  insert_edges_ref, probe_counted, query_edges_ref)
 
 IMPLS = ("auto", "pallas", "jnp", "oracle")
 
@@ -66,6 +67,20 @@ TRANSPOSE = "transpose"
 SYMMETRIC = "symmetric"
 
 _STATIC = ("impl", "interpret", "queries_per_tile", "use_commit_kernel")
+
+
+def count_probes(probes) -> None:
+    """``probe.<loop>.{hops,visits,width}`` counters on the innermost open
+    ``obs`` span, from a dict of ``ProbeCount`` (None: the impl counts
+    nothing); a no-op with tracing off.  The device values ride the span's
+    one read at its exit (or its ``fetch``)."""
+    if not obs.trace.enabled():
+        return
+    for key, pc in probes.items():
+        if pc is not None:
+            obs.count(f"probe.{key}.hops", pc.hops)
+            obs.count(f"probe.{key}.visits", pc.visits)
+            obs.count(f"probe.{key}.width", pc.width)
 
 
 def _copy_aliased(tree):
@@ -96,16 +111,19 @@ def _resolve(impl: str, interpret: Optional[bool]):
 
 
 def _probe_dispatch(g, bucket, dst, valid, *, impl, interpret, qpt):
+    """(found, slab, lane, ProbeCount | None): the Pallas probe reports no
+    count."""
     if impl == "pallas":
         start = jnp.where(valid, bucket, INVALID_SLAB).astype(jnp.int32)
-        return slab_probe_pallas(g.keys, g.next_slab, start, dst,
-                                 queries_per_tile=qpt, interpret=interpret)
-    return probe(g, bucket, dst, valid)
+        return (*slab_probe_pallas(g.keys, g.next_slab, start, dst,
+                                   queries_per_tile=qpt,
+                                   interpret=interpret), None)
+    return probe_counted(g, bucket, dst, valid)
 
 
 def _classify(g, src, dst, *, impl, interpret, qpt):
     """Shared front half: hash → one variadic stable sort → dup-collapse →
-    chain-walk probe, all on the sorted batch."""
+    chain-walk probe, all on the sorted batch (and the probe's count)."""
     B = src.shape[0]
     valid = batch_valid(g, src, dst)
     b = edge_buckets(g, src, dst, valid)
@@ -122,13 +140,17 @@ def _classify(g, src, dst, *, impl, interpret, qpt):
         same_prev = same_prev.at[1:].set(
             (b_s[1:] == b_s[:-1]) & (dst_s[1:] == dst_s[:-1]))
     cand = valid_s & ~same_prev
-    found, slab, lane = _probe_dispatch(g, b_s, dst_s, cand, impl=impl,
-                                        interpret=interpret, qpt=qpt)
-    return order, b_s, src_s, dst_s, cand, found, slab, lane
+    found, slab, lane, count = _probe_dispatch(
+        g, b_s, dst_s, cand, impl=impl, interpret=interpret, qpt=qpt)
+    return order, b_s, src_s, dst_s, cand, found, slab, lane, count
 
 
 # ----------------------------------------------------------------------------
-# engine bodies (traced; jitted by the public entry points below)
+# engine bodies (traced; jitted by the public entry points below).  The
+# counted bodies also return their probe loop's ``ProbeCount`` (None for the
+# oracle and Pallas impls): ``_query_body``, ``_update_views_body`` (named
+# as the programs a device trace shows) and ``_*_body_counted``; the other
+# bodies drop it.
 # ----------------------------------------------------------------------------
 
 def _query_body(g, src, dst, *, impl="auto", interpret=None,
@@ -138,27 +160,33 @@ def _query_body(g, src, dst, *, impl="auto", interpret=None,
     src = src.astype(jnp.uint32)
     dst = dst.astype(jnp.uint32)
     if impl == "oracle":
-        return query_edges_ref(g, src, dst)
+        return query_edges_ref(g, src, dst), None
     valid = batch_valid(g, src, dst)
     b = edge_buckets(g, src, dst, valid)
-    found, _, _ = _probe_dispatch(g, b, dst, valid, impl=impl,
-                                  interpret=interpret, qpt=queries_per_tile)
-    return found & valid
+    found, _, _, count = _probe_dispatch(
+        g, b, dst, valid, impl=impl, interpret=interpret,
+        qpt=queries_per_tile)
+    return found & valid, count
 
 
-def _insert_body(g, src, dst, w=None, *, impl="auto", interpret=None,
-                 queries_per_tile=256, use_commit_kernel=False):
+def _query_found(g, src, dst, **kw):
+    return _query_body(g, src, dst, **kw)[0]
+
+
+def _insert_body_counted(g, src, dst, w=None, *, impl="auto",
+                         interpret=None, queries_per_tile=256,
+                         use_commit_kernel=False):
     impl, interpret = _resolve(impl, interpret)
     src = src.astype(jnp.uint32)
     dst = dst.astype(jnp.uint32)
     if impl == "oracle":
-        return insert_edges_ref(g, src, dst, w)
+        return (*insert_edges_ref(g, src, dst, w), None)
     B = src.shape[0]
     W = SLAB_WIDTH
     nb = g.n_buckets
     cap = g.capacity_slabs
 
-    order, b_s, src_s, dst_s, cand, exists, _, _ = _classify(
+    order, b_s, src_s, dst_s, cand, exists, _, _, count = _classify(
         g, src, dst, impl=impl, interpret=interpret, qpt=queries_per_tile)
     w_s = None if w is None else w[order]
     new = cand & ~exists
@@ -276,19 +304,23 @@ def _insert_body(g, src, dst, w=None, *, impl="auto", interpret=None,
         slab_new=slab_new,
         degree=degree,
         n_edges=g.n_edges + jnp.sum(new.astype(jnp.int32)))
-    return g2, inserted
+    return g2, inserted, count
 
 
-def _delete_body(g, src, dst, *, impl="auto", interpret=None,
-                 queries_per_tile=256, use_commit_kernel=False):
+def _insert_body(g, src, dst, w=None, **kw):
+    return _insert_body_counted(g, src, dst, w, **kw)[:2]
+
+
+def _delete_body_counted(g, src, dst, *, impl="auto", interpret=None,
+                         queries_per_tile=256, use_commit_kernel=False):
     impl, interpret = _resolve(impl, interpret)
     src = src.astype(jnp.uint32)
     dst = dst.astype(jnp.uint32)
     if impl == "oracle":
-        return delete_edges_ref(g, src, dst)
+        return (*delete_edges_ref(g, src, dst), None)
     B = src.shape[0]
 
-    order, b_s, src_s, dst_s, cand, found, slab, lane = _classify(
+    order, b_s, src_s, dst_s, cand, found, slab, lane, count = _classify(
         g, src, dst, impl=impl, interpret=interpret, qpt=queries_per_tile)
     hit = found & cand
 
@@ -308,7 +340,11 @@ def _delete_body(g, src, dst, *, impl="auto", interpret=None,
     g2 = dataclasses.replace(
         g, keys=keys, degree=degree,
         n_edges=g.n_edges - jnp.sum(hit.astype(jnp.int32)))
-    return g2, deleted
+    return g2, deleted, count
+
+
+def _delete_body(g, src, dst, **kw):
+    return _delete_body_counted(g, src, dst, **kw)[:2]
 
 
 # ----------------------------------------------------------------------------
@@ -324,21 +360,32 @@ _delete_jit_don = jax.jit(_delete_body, static_argnames=_STATIC,
                           donate_argnums=(0,))
 
 
-@timed_dispatch("slab_update")
-def query_edges(g: SlabGraph, src, dst, *, impl: str = "auto",
-                interpret: Optional[bool] = None,
-                queries_per_tile: int = 256,
-                use_commit_kernel: bool = False) -> jnp.ndarray:
-    """Batched membership query (paper's query benchmark, Fig. 5).
-
-    Lanes with out-of-range src or sentinel (EMPTY/TOMBSTONE/INVALID) dst
-    return False instead of probing with a garbage key.
-    (``use_commit_kernel`` is accepted for engine-kwarg uniformity;
-    queries never commit.)
-    """
+@timed_dispatch("slab_update", op="query_edges")
+def _query_edges(g: SlabGraph, src, dst, *, impl: str = "auto",
+                 interpret: Optional[bool] = None,
+                 queries_per_tile: int = 256,
+                 use_commit_kernel: bool = False
+                 ) -> Tuple[jnp.ndarray, Optional[ProbeCount]]:
+    """``query_edges`` and its probe loop's ``ProbeCount`` (None for the
+    oracle and Pallas impls): the same compiled program, so reading the
+    count or not never recompiles."""
     return _query_jit(g, src, dst, impl=impl, interpret=interpret,
                       queries_per_tile=queries_per_tile,
                       use_commit_kernel=use_commit_kernel)
+
+
+def query_edges(g: SlabGraph, src, dst, **kw) -> jnp.ndarray:
+    """Batched membership query (paper's query benchmark, Fig. 5).
+
+    Lanes with out-of-range src or sentinel (EMPTY/TOMBSTONE/INVALID) dst
+    return False instead of probing with a garbage key.  Keywords as
+    ``_query_edges`` (``use_commit_kernel`` is accepted for
+    engine-kwarg uniformity; queries never commit).  With tracing on, the
+    probe's count rides the caller's span as ``probe.query.*``.
+    """
+    found, count = _query_edges(g, src, dst, **kw)
+    count_probes({"query": count})
+    return found
 
 
 @timed_dispatch("slab_update")
@@ -444,7 +491,7 @@ def _query_shards_body(graphs, src, dst, *, impl="auto", interpret=None,
     del use_commit_kernel
     kw = dict(impl=impl, interpret=interpret,
               queries_per_tile=queries_per_tile)
-    return jax.vmap(lambda g, s, d: _query_body(g, s, d, **kw))(
+    return jax.vmap(lambda g, s, d: _query_found(g, s, d, **kw))(
         graphs, src, dst)
 
 
@@ -502,40 +549,48 @@ def _update_views_body(views, ins, dels, *, roles, impl="auto",
     views = list(views)
     fidx = roles.index(FORWARD)
     ins_mask = del_mask = None
+    probes = {}
 
     if dels is not None:
         ds, dd = dels
         # forward first: the symmetric union consults the post-delete
         # forward view to decide whether the reverse direction survives.
-        views[fidx], del_mask = _delete_body(views[fidx], ds, dd, **kw)
+        views[fidx], del_mask, probes[f"{FORWARD}.delete"] = \
+            _delete_body_counted(views[fidx], ds, dd, **kw)
         for i, role in enumerate(roles):
             if i == fidx:
                 continue
             if role == TRANSPOSE:
-                views[i], _ = _delete_body(views[i], dd, ds, **kw)
+                views[i], _, probes[f"{role}.delete"] = \
+                    _delete_body_counted(views[i], dd, ds, **kw)
             elif role == SYMMETRIC:
-                rev = _query_body(views[fidx], dd, ds, **kw)
+                rev, probes[f"{role}.reverse"] = _query_body(
+                    views[fidx], dd, ds, **kw)
                 gone = ~rev
                 s2 = jnp.concatenate([jnp.where(gone, ds, INVALID_VERTEX),
                                       jnp.where(gone, dd, INVALID_VERTEX)])
                 d2 = jnp.concatenate([dd, ds])
-                views[i], _ = _delete_body(views[i], s2, d2, **kw)
+                views[i], _, probes[f"{role}.delete"] = \
+                    _delete_body_counted(views[i], s2, d2, **kw)
 
     if ins is not None:
         s, d, w = ins
-        views[fidx], ins_mask = _insert_body(views[fidx], s, d, w, **kw)
+        views[fidx], ins_mask, probes[f"{FORWARD}.insert"] = \
+            _insert_body_counted(views[fidx], s, d, w, **kw)
         for i, role in enumerate(roles):
             if i == fidx:
                 continue
             if role == TRANSPOSE:
-                views[i], _ = _insert_body(views[i], d, s, w, **kw)
+                views[i], _, probes[f"{role}.insert"] = \
+                    _insert_body_counted(views[i], d, s, w, **kw)
             elif role == SYMMETRIC:
                 w2 = None if w is None else jnp.concatenate([w, w])
-                views[i], _ = _insert_body(
-                    views[i], jnp.concatenate([s, d]),
-                    jnp.concatenate([d, s]), w2, **kw)
+                views[i], _, probes[f"{role}.insert"] = \
+                    _insert_body_counted(
+                        views[i], jnp.concatenate([s, d]),
+                        jnp.concatenate([d, s]), w2, **kw)
 
-    return tuple(views), ins_mask, del_mask
+    return tuple(views), ins_mask, del_mask, probes
 
 
 _VIEWS_STATIC = ("roles",) + _STATIC
@@ -544,26 +599,18 @@ _views_jit_don = jax.jit(_update_views_body, static_argnames=_VIEWS_STATIC,
                          donate_argnums=(0,))
 
 
-@timed_dispatch("slab_update")
-def update_views(views: Tuple[SlabGraph, ...], roles: Tuple[str, ...],
-                 ins=None, dels=None, *, impl: str = "auto",
-                 interpret: Optional[bool] = None,
-                 queries_per_tile: int = 256,
-                 use_commit_kernel: bool = False, donate: bool = True):
-    """Apply one canonical batch to every live view in a single dispatch.
-
-    ``views`` / ``roles`` are parallel tuples; roles come from
-    {FORWARD, TRANSPOSE, SYMMETRIC} and must include FORWARD.  The
-    transpose and symmetric batches are *derived* from the canonical
-    (src, dst) batch on device (swap / concat) — callers hash/dedup/pad
-    exactly once.  ``ins`` is ``(src, dst, w | None)``, ``dels`` is
-    ``(src, dst)``; deletes apply before inserts.  Returns
-    ``(new_views, inserted_mask, deleted_mask)`` with masks over the
-    forward view's canonical batch.
-
-    Donation is ON by default: every view's buffers are consumed and
-    mutated in place — thread the returned views.
-    """
+@timed_dispatch("slab_update", op="update_views")
+def _update_views(views: Tuple[SlabGraph, ...], roles: Tuple[str, ...],
+                  ins=None, dels=None, *, impl: str = "auto",
+                  interpret: Optional[bool] = None,
+                  queries_per_tile: int = 256,
+                  use_commit_kernel: bool = False, donate: bool = True):
+    """``update_views`` plus the ``ProbeCount`` of every probe loop, keyed
+    ``<role>.<op>``: ``forward``/``transpose``/``symmetric`` ×
+    ``delete``/``insert``, and ``symmetric.reverse`` for the symmetric
+    view's reverse-existence query (a value is None where the impl reports
+    no count).  Returns ``(new_views, inserted_mask, deleted_mask,
+    probes)``; one compiled program with ``update_views``."""
     if FORWARD not in roles:
         raise ValueError("update_views requires a forward view")
     fn = _views_jit_don if donate else _views_jit
@@ -574,8 +621,31 @@ def update_views(views: Tuple[SlabGraph, ...], roles: Tuple[str, ...],
               use_commit_kernel=use_commit_kernel)
 
 
+def update_views(views: Tuple[SlabGraph, ...], roles: Tuple[str, ...],
+                 ins=None, dels=None, **kw):
+    """Apply one canonical batch to every live view in a single dispatch.
+
+    ``views`` / ``roles`` are parallel tuples; roles come from
+    {FORWARD, TRANSPOSE, SYMMETRIC} and must include FORWARD.  The
+    transpose and symmetric batches are *derived* from the canonical
+    (src, dst) batch on device (swap / concat) — callers hash/dedup/pad
+    exactly once.  ``ins`` is ``(src, dst, w | None)``, ``dels`` is
+    ``(src, dst)``; deletes apply before inserts.  Returns
+    ``(new_views, inserted_mask, deleted_mask)`` with masks over the
+    forward view's canonical batch.  Keywords as ``_update_views``.
+    With tracing on, every probe loop's count rides the caller's span as
+    ``probe.<role>.<op>.*``.
+
+    Donation is ON by default: every view's buffers are consumed and
+    mutated in place — thread the returned views.
+    """
+    *out, probes = _update_views(views, roles, ins, dels, **kw)
+    count_probes(probes)
+    return tuple(out)
+
+
 # ----------------------------------------------------------------------------
-# shard_map-compatible local entry points (DESIGN.md §9)
+# shard_map compatibility (DESIGN.md §9)
 #
 # The traced engine bodies are safe to call INSIDE a ``shard_map`` body on a
 # shard-local SlabGraph: they contain no host sync, no jit boundary, and no
@@ -583,19 +653,15 @@ def update_views(views: Tuple[SlabGraph, ...], roles: Tuple[str, ...],
 # padding rows sort last and scatter with ``mode="drop"``, so the resulting
 # pools are a function of the valid edges' relative order only (not of the
 # padding POSITIONS).  That padding-position independence is what makes the
-# single-program sharded epoch bit-identical to the vmap fallback even though
-# the all-to-all routed batches carry interior (not tail) padding.  The
-# ``*_local`` aliases are that contract's public names; the jitted
-# ``query/insert/delete_edges`` wrappers above remain the single-graph API.
+# single-program sharded epoch (``stream/sharded_store.py``, which calls
+# ``_query_body`` and the ``_*_body_counted`` bodies) bit-identical to the
+# vmap fallback even though the all-to-all routed batches carry interior
+# (not tail) padding.
 # ----------------------------------------------------------------------------
 
-query_edges_local = _query_body
-insert_edges_local = _insert_body
-delete_edges_local = _delete_body
 
-
-__all__ = ["IMPLS", "FORWARD", "TRANSPOSE", "SYMMETRIC",
+__all__ = ["IMPLS", "FORWARD", "TRANSPOSE", "SYMMETRIC", "ProbeCount",
            "query_edges", "insert_edges", "delete_edges",
-           "query_edges_local", "insert_edges_local", "delete_edges_local",
-           "apply_update", "update_views", "update_shards", "query_shards",
+           "apply_update", "update_views", "count_probes",
+           "update_shards", "query_shards",
            "slab_probe_pallas", "slab_commit_pallas"]

@@ -28,6 +28,7 @@ Semantics notes (shared by oracle and engine — the contracts the tests pin):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -58,9 +59,23 @@ def edge_buckets(g: SlabGraph, src: jnp.ndarray, dst: jnp.ndarray,
     return jnp.where(valid, b, 0).astype(jnp.int32)
 
 
-def probe(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
-          valid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Walk each query's slab list; return (found, slab, lane) per query.
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("hops", "visits"), meta_fields=("width",))
+@dataclasses.dataclass(frozen=True)
+class ProbeCount:
+    """The work of one probe loop: ``hops`` trips of its ``while_loop``,
+    ``visits`` the lanes still walking a chain summed over the trips, and
+    ``width`` the (static) batch width, so ``visits / (hops * width)`` is
+    the share of gathered rows that belonged to a live query."""
+    hops: jnp.ndarray
+    visits: jnp.ndarray
+    width: int
+
+
+def probe_counted(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
+                  valid: jnp.ndarray):
+    """Walk each query's slab list; return (found, slab, lane) per query
+    and the loop's ``ProbeCount``.
 
     The inner body is the warp-cooperative slab probe: one gathered slab row
     (128 lanes) per query per hop, lane-wide equality, ``ballot``→``any``.
@@ -72,16 +87,19 @@ def probe(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
     found = jnp.zeros((B,), dtype=bool)
     slab = jnp.full((B,), INVALID_SLAB, dtype=jnp.int32)
     lane = jnp.full((B,), -1, dtype=jnp.int32)
+    # per-lane trips, summed once after the loop: an elementwise add per
+    # trip, no reduction across the batch inside the loop
+    steps = jnp.zeros((B,), jnp.int32)
 
     def cond(state):
         cur, *_ = state
         return jnp.any(cur != INVALID_SLAB)
 
     def body(state):
-        cur, found, slab, lane = state
+        cur, found, slab, lane, hops, steps = state
+        live = cur != INVALID_SLAB
         rows = g.keys[jnp.maximum(cur, 0)]                       # (B, 128)
-        hit = (rows == dst[:, None].astype(jnp.uint32)) \
-              & (cur != INVALID_SLAB)[:, None]
+        hit = (rows == dst[:, None].astype(jnp.uint32)) & live[:, None]
         hit_any = jnp.any(hit, axis=1)
         hit_lane = jnp.argmax(hit, axis=1).astype(jnp.int32)
         newly = hit_any & ~found
@@ -89,11 +107,20 @@ def probe(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
         lane = jnp.where(newly, hit_lane, lane)
         found = found | hit_any
         nxt = g.next_slab[jnp.maximum(cur, 0)]
-        cur = jnp.where((cur == INVALID_SLAB) | found, INVALID_SLAB, nxt)
-        return cur, found, slab, lane
+        cur = jnp.where(~live | found, INVALID_SLAB, nxt)
+        return (cur, found, slab, lane, hops + 1,
+                steps + live.astype(jnp.int32))
 
-    _, found, slab, lane = jax.lax.while_loop(cond, body,
-                                              (cur, found, slab, lane))
+    _, found, slab, lane, hops, steps = jax.lax.while_loop(
+        cond, body, (cur, found, slab, lane, jnp.zeros((), jnp.int32), steps))
+    visits = jnp.sum(steps)
+    return found, slab, lane, ProbeCount(hops, visits, B)
+
+
+def probe(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
+          valid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``probe_counted`` without the count: (found, slab, lane) per query."""
+    found, slab, lane, _ = probe_counted(g, bucket, dst, valid)
     return found, slab, lane
 
 

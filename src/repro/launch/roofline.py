@@ -122,33 +122,6 @@ def analyse(rec: Dict) -> Optional[Dict]:
     }
 
 
-def kernel_table(kernels: Dict[str, Dict]) -> str:
-    """Achieved-vs-peak table from MEASURED kernel counters.
-
-    ``kernels`` is ``repro.obs.kernel_summary()`` (or the ``"kernels"``
-    section of a ``launch/serve.py --metrics-json`` export): per
-    (family.op[pool shape]) the steady-state wall seconds and the measured
-    bytes moved.  Achieved bytes/s = bytes / steady_s, reported against
-    the HBM roof — the measured counterpart of the static HLO analysis
-    above (dispatch wall time includes host+launch overhead, so the
-    fraction is a lower bound on what the kernel body sustains).
-    """
-    hdr = ("| kernel [pool shape] | calls | compile s | steady ms/call | "
-           "GB moved | achieved GB/s | % HBM roof |")
-    lines = [hdr, "|" + "---|" * 7]
-    for key in sorted(kernels):
-        s = kernels[key]
-        steady_calls = max(1, int(s["steady_calls"]))
-        steady_s = float(s["steady_s"])
-        nbytes = float(s["bytes"])
-        bps = nbytes / steady_s if steady_s > 0 else 0.0
-        lines.append(
-            f"| {key} | {int(s['calls'])} | {float(s['compile_s']):.3f} | "
-            f"{1e3 * steady_s / steady_calls:.3f} | {nbytes / 1e9:.4f} | "
-            f"{bps / 1e9:.2f} | {100.0 * bps / HBM_BW:.2f} |")
-    return "\n".join(lines)
-
-
 MOVE_NOTES = {
     "compute": "raise MXU utilisation: larger fused matmul tiles / bf16 "
                "throughout / drop redundant recompute",
@@ -166,20 +139,7 @@ def main():
     ap.add_argument("--mesh", default="pod",
                     help="which mesh's table to print (pod = single-pod "
                          "roofline per the assignment)")
-    ap.add_argument("--kernel-metrics", default=None, metavar="PATH",
-                    help="achieved-vs-peak table from MEASURED kernel "
-                         "counters (a launch/serve.py --metrics-json "
-                         "export) instead of the static HLO analysis")
     args = ap.parse_args()
-
-    if args.kernel_metrics:
-        rec = json.loads(Path(args.kernel_metrics).read_text())
-        kernels = rec.get("kernels", rec)
-        table = kernel_table(kernels)
-        print(table)
-        if args.md:
-            Path(args.md).write_text(table + "\n")
-        return
 
     rows = []
     skipped = []

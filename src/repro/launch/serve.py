@@ -449,8 +449,7 @@ def main():
                 steady = st["steady_s"] / max(1, st["steady_calls"])
                 print(f"[serve] {key:44s} calls={st['calls']:<5d} "
                       f"compile={st['compile_s']:.3f}s "
-                      f"steady={1e3 * steady:.2f}ms "
-                      f"bytes={st['bytes']}")
+                      f"steady={1e3 * steady:.2f}ms")
     if args.metrics_json:
         import json
         summary = obs.get_registry().summary()

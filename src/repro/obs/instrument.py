@@ -7,12 +7,7 @@ per (family, op, pool shape):
 * invocation count,
 * FIRST-call wall time per shape — dominated by jit compilation — kept
   separate from the steady-state run-time histogram, so compile cost
-  never pollutes the latency quantiles,
-* a bytes-moved estimate (sum of jax-array argument + result ``nbytes``
-  by default — the traffic a memory-bound kernel actually pays, and an
-  upper bound under donation aliasing; entry points can pass a tighter
-  ``bytes_fn``).  ``launch/roofline.py --kernel-metrics`` turns these
-  measured counters into achieved-vs-peak bytes/s.
+  never pollutes the latency quantiles.
 
 Neutrality contract (tests/test_obs.py): the wrapper NEVER changes what
 the wrapped function computes — enabled, it only times, blocks on the
@@ -35,7 +30,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 from jax.core import Tracer
@@ -75,11 +70,6 @@ def _arrays(tree):
             if isinstance(x, jax.Array)]
 
 
-def pool_bytes(tree) -> int:
-    """Total bytes of every jax array leaf in ``tree``."""
-    return sum(int(a.nbytes) for a in _arrays(tree))
-
-
 def _shape_sig(args) -> str:
     """Pool-shape signature: the first SlabGraph-ish arg's key-pool shape,
     else the first array leaf's shape — what jit specializes on."""
@@ -103,7 +93,7 @@ def kernel_stats() -> Dict[Tuple[str, str, str], Dict[str, float]]:
 
 def kernel_summary() -> Dict[str, Dict[str, float]]:
     """JSON-friendly per-(family.op[shape]) record: calls, compile s,
-    steady-state s, measured bytes — the roofline's input."""
+    steady-state s."""
     out = {}
     for (family, op, shape), s in kernel_stats().items():
         out[f"{family}.{op}[{shape}]"] = {
@@ -112,7 +102,6 @@ def kernel_summary() -> Dict[str, Dict[str, float]]:
             "compile_s": s["compile_s"],
             "steady_calls": int(s["steady_calls"]),
             "steady_s": s["steady_s"],
-            "bytes": int(s["bytes"]),
         }
     return out
 
@@ -122,15 +111,13 @@ def reset_kernel_stats() -> None:
         _KERNEL_STATS.clear()
 
 
-def _record(family: str, op: str, shape: str, dt_s: float,
-            nbytes: int) -> None:
+def _record(family: str, op: str, shape: str, dt_s: float) -> None:
     key = (family, op, shape)
     with _lock:
         s = _KERNEL_STATS.get(key)
         if s is None:
             s = _KERNEL_STATS[key] = {"calls": 0, "compile_s": 0.0,
-                                      "steady_calls": 0, "steady_s": 0.0,
-                                      "bytes": 0}
+                                      "steady_calls": 0, "steady_s": 0.0}
         first = s["calls"] == 0
         s["calls"] += 1
         if first:
@@ -139,18 +126,15 @@ def _record(family: str, op: str, shape: str, dt_s: float,
         else:
             s["steady_calls"] += 1
             s["steady_s"] += dt_s
-            s["bytes"] += nbytes
     name = f"kernel.{family}.{op}"
     metrics.inc(f"{name}.calls")
     if first:
         metrics.observe(f"{name}.compile", dt_s)
     else:
-        metrics.inc(f"{name}.bytes", nbytes)
         metrics.observe(f"{name}.run", dt_s)
 
 
-def timed_dispatch(family: str, op: Optional[str] = None,
-                   bytes_fn: Optional[Callable] = None):
+def timed_dispatch(family: str, op: Optional[str] = None):
     """Decorator factory for kernel-family entry points (module doc)."""
 
     def deco(fn):
@@ -188,12 +172,7 @@ def timed_dispatch(family: str, op: Optional[str] = None,
                         a.block_until_ready()
                 dt_ns = time.perf_counter_ns() - t0
                 flight.record(fl_code, dt_ns)
-                dt = dt_ns / 1e9
-                if bytes_fn is not None:
-                    nbytes = int(bytes_fn(args, kwargs, out))
-                else:
-                    nbytes = pool_bytes(args) + pool_bytes(out)
-                _record(family, op_name, shape, dt, nbytes)
+                _record(family, op_name, shape, dt_ns / 1e9)
             finally:
                 _tls.depth = 0
             return out
@@ -204,5 +183,5 @@ def timed_dispatch(family: str, op: Optional[str] = None,
     return deco
 
 
-__all__ = ["timed_dispatch", "pool_bytes", "kernel_stats", "kernel_summary",
+__all__ = ["timed_dispatch", "kernel_stats", "kernel_summary",
            "reset_kernel_stats"]

@@ -130,20 +130,23 @@ class PropertyRegistry:
         if missed is None or (limit is not None and len(missed) > limit):
             # log truncated past the property's version, or a replay
             # dearer than one static recompute
-            with obs.span("property.refresh", prop=name):
+            with obs.span("property.refresh", prop=name) as sp:
                 e.state = e.spec.refresh(self.store)
+                sp.sync_on(e.state)
             obs.inc(f"property.{name}.refresh")
         elif e.spec.collapse_replay and missed:
             with obs.span("property.replay", prop=name, collapsed=True,
-                          depth=len(missed)):
+                          depth=len(missed)) as sp:
                 e.state = e.spec.on_batch(self.store, e.state, missed[-1])
+                sp.sync_on(e.state)
             obs.inc(f"property.{name}.replay_collapsed")
             obs.observe(f"property.replay_depth.{name}", len(missed))
         else:
             with obs.span("property.replay", prop=name,
-                          depth=len(missed)):
+                          depth=len(missed)) as sp:
                 for batch in missed:
                     e.state = e.spec.on_batch(self.store, e.state, batch)
+                sp.sync_on(e.state)
             obs.inc(f"property.{name}.replay", max(1, len(missed)))
             obs.observe(f"property.replay_depth.{name}", len(missed))
         e.version = self.store.version

@@ -168,6 +168,9 @@ class RequestPipeline:
         self.health = health
         self.health_every = int(health_every)
         self._since_health = 0
+        # the ``req`` tag of the pipeline's spans: one number per
+        # dispatched request group, shared by the spans it nests
+        self._req = 0
         if breaker is not None:
             # post-mortem bundles carry the breaker state at death
             _postmortem.register_breaker(breaker)
@@ -236,6 +239,8 @@ class RequestPipeline:
         while i < len(requests):
             r = requests[i]
             j = i + 1
+            self._req += 1
+            req = self._req
             if isinstance(r, UpdateBatch):
                 while (self.coalesce and j < len(requests)
                        and isinstance(requests[j], UpdateBatch)):
@@ -253,7 +258,8 @@ class RequestPipeline:
                     i = j
                     continue
                 try:
-                    with obs.span("pipeline.update", coalesced=j - i):
+                    with obs.span("pipeline.update", req=req,
+                                  coalesced=j - i):
                         payload = self._apply_updates(list(requests[i:j]))
                 except InjectedCrash:
                     raise                # simulated kill: nothing catches it
@@ -280,7 +286,7 @@ class RequestPipeline:
                        and isinstance(requests[j], MembershipQuery)):
                     j += 1
                 t0 = time.perf_counter()
-                with obs.span("pipeline.member", merged=j - i):
+                with obs.span("pipeline.member", req=req, merged=j - i):
                     payloads = self._run_membership(list(requests[i:j]))
                 dt = time.perf_counter() - t0
                 self._observe("member", dt, j - i)
@@ -289,13 +295,13 @@ class RequestPipeline:
                                             p, dt)
             elif isinstance(r, NeighborsQuery):
                 t0 = time.perf_counter()
-                with obs.span("pipeline.neighbors"):
+                with obs.span("pipeline.neighbors", req=req):
                     ef = self.store.neighbors(r.vertices,
                                               out_capacity=r.out_capacity)
-                n = int(ef.size)
-                payload = {"src": np.asarray(ef.src)[:n],
-                           "dst": np.asarray(ef.dst)[:n],
-                           "count": n, "overflow": bool(ef.overflow)}
+                    n = int(ef.size)
+                    payload = {"src": np.asarray(ef.src)[:n],
+                               "dst": np.asarray(ef.dst)[:n],
+                               "count": n, "overflow": bool(ef.overflow)}
                 dt = time.perf_counter() - t0
                 self._observe("neighbors", dt)
                 responses[i] = Response("neighbors", self.store.version,
@@ -323,8 +329,10 @@ class RequestPipeline:
                         {"name": r.name, "value": value, "stale": True,
                          "staleness": self.store.version - version}, dt)
                 else:
-                    with obs.span("pipeline.property", prop=r.name):
+                    with obs.span("pipeline.property", req=req,
+                                  prop=r.name) as sp:
                         value = self.registry.read(r.name)
+                        sp.sync_on(value)
                     dt = time.perf_counter() - t0
                     self._observe("property", dt)
                     responses[i] = Response("property", self.store.version,

@@ -65,9 +65,10 @@ from ..distributed.sharded_graph import (SHARD_AXIS, ShardedSlabGraph,
                                          shard_from_edges_host, shard_slice,
                                          triangles_sharded, wcc_sharded)
 from ..distributed.sharded_graph import place_on_mesh as _place_graph
-from ..kernels.slab_update.ops import (_copy_aliased, delete_edges_local,
-                                       insert_edges_local,
-                                       query_edges_local)
+from ..kernels.slab_update.ops import (ProbeCount, _copy_aliased,
+                                       _delete_body_counted,
+                                       _insert_body_counted, _query_body,
+                                       count_probes)
 from ..resilience import faults
 from ..resilience.guard import run_with_retries, validate_batch
 from .store import (ALL_VIEWS, FORWARD, SYMMETRIC, TRANSPOSE, AppliedBatch,
@@ -80,6 +81,17 @@ from .store import (ALL_VIEWS, FORWARD, SYMMETRIC, TRANSPOSE, AppliedBatch,
 # the fused multi-view sharded apply — route + mutate every view in ONE jit
 # ----------------------------------------------------------------------------
 
+def _across_shards(pc: Optional[ProbeCount], n_shards: int
+                   ) -> Optional[ProbeCount]:
+    """One probe loop's count over every shard, from per-shard (S,) counts
+    of ``pc.width`` lanes each: the loop ends with its slowest shard, so
+    ``hops`` is the most any shard made, over all S x width lanes."""
+    if pc is None:
+        return None
+    return ProbeCount(jnp.max(pc.hops), jnp.sum(pc.visits),
+                      n_shards * pc.width)
+
+
 def _sharded_apply_body(views, ins, dels, *, roles, n_shards, caps,
                         impl="auto", interpret=None, queries_per_tile=256):
     kw = dict(impl=impl, interpret=interpret,
@@ -88,19 +100,24 @@ def _sharded_apply_body(views, ins, dels, *, roles, n_shards, caps,
     views = list(views)
     fidx = roles.index(FORWARD)
     ins_mask = del_mask = None
+    probes = {}
 
-    def vdel(sg, s, d, cap):
+    def vdel(sg, s, d, cap, key):
         bs, bd, _, origin, _ = _route_body(s, d, None, n_shards=n_shards,
                                            cap=cap)
-        g, m = jax.vmap(lambda g, a, b: delete_edges_local(g, a, b, **kw))(
+        g, m, pc = jax.vmap(
+            lambda g, a, b: _delete_body_counted(g, a, b, **kw))(
             sg.graphs, bs, bd)
+        probes[key] = _across_shards(pc, n_shards)
         return dataclasses.replace(sg, graphs=g), m, origin
 
-    def vins(sg, s, d, w, cap):
+    def vins(sg, s, d, w, cap, key):
         bs, bd, bw, origin, _ = _route_body(s, d, w, n_shards=n_shards,
                                             cap=cap)
-        g, m = jax.vmap(lambda g, a, b, c: insert_edges_local(g, a, b, c, **kw))(
+        g, m, pc = jax.vmap(
+            lambda g, a, b, c: _insert_body_counted(g, a, b, c, **kw))(
             sg.graphs, bs, bd, bw)
+        probes[key] = _across_shards(pc, n_shards)
         return dataclasses.replace(sg, graphs=g), m, origin
 
     if dels is not None:
@@ -108,49 +125,56 @@ def _sharded_apply_body(views, ins, dels, *, roles, n_shards, caps,
         p = ds.shape[0]
         # forward first: the symmetric union consults the post-delete
         # forward view to decide whether the reverse direction survives.
-        views[fidx], m, origin = vdel(views[fidx], ds, dd, fwd_del)
+        views[fidx], m, origin = vdel(views[fidx], ds, dd, fwd_del,
+                                      f"{FORWARD}.delete")
         del_mask = _scatter_back(m, origin, p)
         for i, role in enumerate(roles):
             if i == fidx:
                 continue
             if role == TRANSPOSE:
-                views[i], _, _ = vdel(views[i], dd, ds, tr_del)
+                views[i], _, _ = vdel(views[i], dd, ds, tr_del,
+                                      f"{role}.delete")
             elif role == SYMMETRIC:
                 bs, bd, _, qorig, _ = _route_body(dd, ds, None,
                                                   n_shards=n_shards,
                                                   cap=tr_del)
-                found = jax.vmap(lambda g, a, b: query_edges_local(
+                found, pc = jax.vmap(lambda g, a, b: _query_body(
                     g, a, b, impl=impl, interpret=interpret,
                     queries_per_tile=queries_per_tile))(
                     views[fidx].graphs, bs, bd)
+                probes[f"{role}.reverse"] = _across_shards(pc, n_shards)
                 rev = _scatter_back(found, qorig, p)
                 gone = ~rev
                 s2 = jnp.concatenate([jnp.where(gone, ds, INVALID_VERTEX),
                                       jnp.where(gone, dd, INVALID_VERTEX)])
                 d2 = jnp.concatenate([dd, ds])
-                views[i], _, _ = vdel(views[i], s2, d2, sym_del)
+                views[i], _, _ = vdel(views[i], s2, d2, sym_del,
+                                      f"{role}.delete")
 
     if ins is not None:
         s, d, w = ins
         p = s.shape[0]
-        views[fidx], m, origin = vins(views[fidx], s, d, w, fwd_ins)
+        views[fidx], m, origin = vins(views[fidx], s, d, w, fwd_ins,
+                                      f"{FORWARD}.insert")
         ins_mask = _scatter_back(m, origin, p)
         for i, role in enumerate(roles):
             if i == fidx:
                 continue
             if role == TRANSPOSE:
-                views[i], _, _ = vins(views[i], d, s, w, tr_ins)
+                views[i], _, _ = vins(views[i], d, s, w, tr_ins,
+                                      f"{role}.insert")
             elif role == SYMMETRIC:
                 w2 = None if w is None else jnp.concatenate([w, w])
                 views[i], _, _ = vins(views[i], jnp.concatenate([s, d]),
-                                      jnp.concatenate([d, s]), w2, sym_ins)
+                                      jnp.concatenate([d, s]), w2, sym_ins,
+                                      f"{role}.insert")
 
     # epoch close folded into the same dispatch: update_slab_pointers is an
     # elementwise field replace, so running it on the stacked pools here
     # saves one jitted dispatch per view per epoch on the store hot path
     views = [dataclasses.replace(v, graphs=update_slab_pointers(v.graphs))
              for v in views]
-    return tuple(views), ins_mask, del_mask
+    return tuple(views), ins_mask, del_mask, probes
 
 
 _APPLY_STATIC = ("roles", "n_shards", "caps", "impl", "interpret",
@@ -203,10 +227,21 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
     fwd_del, tr_del, sym_del, fwd_ins, tr_ins, sym_ins = caps
     fidx = roles.index(FORWARD)
     need_rev = len(roles) > 1
+    # each probe loop's per-shard batch width, noted while the body traces
+    widths = {}
 
     def _body(graphs_blk, dl, il):
         gs = [jax.tree.map(lambda x: x[0], g) for g in graphs_blk]
         ins_part = del_part = None
+        counts = {}
+
+        def note(key, out):
+            # the shard's (hops, visits) leave as (1,) rows of an (S,) array
+            *rest, pc = out
+            if pc is not None:
+                widths[key] = pc.width
+                counts[key] = (pc.hops[None], pc.visits[None])
+            return rest[0] if len(rest) == 1 else tuple(rest)
 
         def route(s, d, w, cap):
             # two-level cap: route with per-(source block, owner) PAIR
@@ -250,7 +285,8 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
             ds_l, dd_l = dl
             n_del = ds_l.shape[0] * n_shards
             bs, bd, _, orig, _ = route(ds_l, dd_l, None, fwd_del)
-            gs[fidx], m = delete_edges_local(gs[fidx], bs, bd, **kw)
+            gs[fidx], m = note(f"{FORWARD}.delete", _delete_body_counted(
+                gs[fidx], bs, bd, **kw))
             del_part = _scatter_back(m, orig, n_del)
             if need_rev:
                 # ONE routed (dst, src) exchange feeds the transpose
@@ -261,9 +297,11 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
                 if i == fidx:
                     continue
                 if role == TRANSPOSE:
-                    gs[i], _ = delete_edges_local(gs[i], rbs, rbd, **kw)
+                    gs[i], _ = note(f"{role}.delete", _delete_body_counted(
+                        gs[i], rbs, rbd, **kw))
                 elif role == SYMMETRIC:
-                    found = query_edges_local(gs[fidx], rbs, rbd, **kwq)
+                    found = note(f"{role}.reverse", _query_body(
+                        gs[fidx], rbs, rbd, **kwq))
                     gone = ~or_across_shards(
                         _scatter_back(found, rorig, n_del))
                     # the symmetric delete RIDES the two exchanges above:
@@ -284,13 +322,15 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
                     cs, cd, _ = compact(sym_del,
                                         jnp.concatenate([s2, s2r]),
                                         jnp.concatenate([d2, d2r]))
-                    gs[i], _ = delete_edges_local(gs[i], cs, cd, **kw)
+                    gs[i], _ = note(f"{role}.delete", _delete_body_counted(
+                        gs[i], cs, cd, **kw))
 
         if il is not None:
             is_l, id_l, iw_l = il
             n_ins = is_l.shape[0] * n_shards
             bs, bd, bw, orig, _ = route(is_l, id_l, iw_l, fwd_ins)
-            gs[fidx], m = insert_edges_local(gs[fidx], bs, bd, bw, **kw)
+            gs[fidx], m = note(f"{FORWARD}.insert", _insert_body_counted(
+                gs[fidx], bs, bd, bw, **kw))
             ins_part = _scatter_back(m, orig, n_ins)
             if need_rev:
                 tbs, tbd, tbw, _, _ = route(id_l, is_l, iw_l, tr_ins)
@@ -298,7 +338,8 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
                 if i == fidx:
                     continue
                 if role == TRANSPOSE:
-                    gs[i], _ = insert_edges_local(gs[i], tbs, tbd, tbw, **kw)
+                    gs[i], _ = note(f"{role}.insert", _insert_body_counted(
+                        gs[i], tbs, tbd, tbw, **kw))
                 elif role == SYMMETRIC:
                     # both directions already delivered: forward bucket
                     # owns the (s, d) half, transpose bucket the (d, s)
@@ -308,13 +349,14 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
                     cs, cd, cw = compact(sym_ins,
                                          jnp.concatenate([bs, tbs]),
                                          jnp.concatenate([bd, tbd]), w2)
-                    gs[i], _ = insert_edges_local(gs[i], cs, cd, cw, **kw)
+                    gs[i], _ = note(f"{role}.insert", _insert_body_counted(
+                        gs[i], cs, cd, cw, **kw))
 
         # epoch close folded into the single program (same as the vmap body)
         gs = [update_slab_pointers(g) for g in gs]
         return (tuple(jax.tree.map(lambda x: x[None], g) for g in gs),
                 None if del_part is None else del_part[None],
-                None if ins_part is None else ins_part[None])
+                None if ins_part is None else ins_part[None], counts)
 
     vec = P(SHARD_AXIS)
     gspecs = tuple(graph_pspecs(g) for g in views)
@@ -322,17 +364,19 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
     def batch_specs(t):
         return jax.tree.map(lambda _: vec, t)
 
-    out_views, del_parts, ins_parts = jax.shard_map(
+    out_views, del_parts, ins_parts, counts = jax.shard_map(
         _body, mesh=mesh,
         in_specs=(gspecs, batch_specs(dels), batch_specs(ins)),
         out_specs=(gspecs,
                    None if dels is None else P(SHARD_AXIS, None),
-                   None if ins is None else P(SHARD_AXIS, None)),
+                   None if ins is None else P(SHARD_AXIS, None), vec),
         check_vma=False)(views, dels, ins)
     # each batch position is owned by exactly one shard: OR the partials
     ins_mask = None if ins_parts is None else ins_parts.any(axis=0)
     del_mask = None if del_parts is None else del_parts.any(axis=0)
-    return out_views, ins_mask, del_mask
+    probes = {k: _across_shards(ProbeCount(h, v, widths[k]), n_shards)
+              for k, (h, v) in counts.items()}
+    return out_views, ins_mask, del_mask, probes
 
 
 _APPLY_SM_STATIC = _APPLY_STATIC + ("mesh",)
@@ -675,29 +719,33 @@ class ShardedGraphStore(VersionedStoreBase):
                     obs.inc("store.sharded.recompiles")
                     obs.instant("sharded_recompile", mode=mode)
                 with obs.span("store.apply.dispatch", mode=mode,
-                              version=self.version, views=len(roles)):
+                              version=self.version,
+                              views=len(roles)) as sp:
                     if mode == "shard_map":
                         in_views = _copy_aliased(
                             tuple(self._views[r].graphs for r in roles))
-                        new_graphs, ins_mask, del_mask = _apply_sm_don(
-                            in_views, dels, ins, roles=roles,
-                            n_shards=S, caps=caps, mesh=self.mesh)
+                        new_graphs, ins_mask, del_mask, probes = \
+                            _apply_sm_don(in_views, dels, ins, roles=roles,
+                                          n_shards=S, caps=caps,
+                                          mesh=self.mesh)
                         for r, g in zip(roles, new_graphs):
                             self._views[r] = dataclasses.replace(
                                 self._views[r], graphs=g)
                     else:
                         in_views = _copy_aliased(
                             tuple(self._views[r] for r in roles))
-                        new_views, ins_mask, del_mask = _apply_jit_don(
-                            in_views, ins, dels, roles=roles, n_shards=S,
-                            caps=caps)
+                        new_views, ins_mask, del_mask, probes = \
+                            _apply_jit_don(in_views, ins, dels, roles=roles,
+                                           n_shards=S, caps=caps)
                         for r, g in zip(roles, new_views):
                             self._views[r] = g
-                    if del_mask is not None:
-                        n_deleted = int(jnp.sum(del_mask.astype(jnp.int32)))
-                    if ins_mask is not None:
-                        n_inserted = int(jnp.sum(
-                            ins_mask.astype(jnp.int32)))
+                    count_probes(probes)
+                    # the applied counts and the probe counters: one
+                    # transfer
+                    n_deleted, n_inserted = (int(n) for n in sp.fetch(
+                        tuple(0 if m is None else
+                              jnp.sum(m.astype(jnp.int32))
+                              for m in (del_mask, ins_mask))))
                 # exact host accounting: the worst shard allocates at most
                 # its routed insert count in new slabs this epoch
                 if len(i_s):

@@ -399,9 +399,10 @@ class VersionedStoreBase:
             stats = self.pool_stats()
         t0 = _time.time()
         with obs.span("store.maintain", version=self.version,
-                      action=action, trigger=trigger):
+                      action=action, trigger=trigger) as sp:
             reports, reclaimed = self._maintain_views(
                 action, policy, shrink=policy.allow_shrink(stats))
+            sp.sync_on(self.views)
         self._epochs_since_maint = 0
         self._deletes_since_maint = 0
         # compaction drops every tombstone; reclamation only frees wholly
@@ -609,18 +610,19 @@ class GraphStore(VersionedStoreBase):
                 n_inserted = n_deleted = 0
                 if ins is not None or dels is not None:
                     with obs.span("store.apply.dispatch",
-                                  version=self.version, views=len(roles)):
+                                  version=self.version,
+                                  views=len(roles)) as sp:
                         new_views, ins_mask, del_mask = update_views(
                             tuple(self._views[r] for r in roles), roles,
                             ins, dels)
                         for r, g in zip(roles, new_views):
                             self._views[r] = g
-                        if del_mask is not None:
-                            n_deleted = int(jnp.sum(
-                                del_mask.astype(jnp.int32)))
-                        if ins_mask is not None:
-                            n_inserted = int(jnp.sum(
-                                ins_mask.astype(jnp.int32)))
+                        # the applied counts and the probe counters that
+                        # update_views put on this span: one transfer
+                        n_deleted, n_inserted = (int(n) for n in sp.fetch(
+                            tuple(0 if m is None else
+                                  jnp.sum(m.astype(jnp.int32))
+                                  for m in (del_mask, ins_mask))))
                 faults.fault_point("apply.pre_close", version=self.version)
                 _flight.record(_FL_DISPATCH, self.version,
                                n_inserted, n_deleted)
@@ -690,11 +692,14 @@ class GraphStore(VersionedStoreBase):
     def query(self, src, dst) -> np.ndarray:
         """Batched edge-membership against the forward view (host arrays in,
         host bool array out, trimmed to the query length)."""
-        src = np.asarray(src, np.uint32)
-        dst = np.asarray(dst, np.uint32)
-        p = _pow2(max(len(src), 1))
-        found = query_edges(self.forward, _pad_u32(src, p), _pad_u32(dst, p))
-        return np.asarray(found)[:len(src)]
+        with obs.span("store.query", version=self.version) as sp:
+            src = np.asarray(src, np.uint32)
+            dst = np.asarray(dst, np.uint32)
+            p = _pow2(max(len(src), 1))
+            found = query_edges(self.forward, _pad_u32(src, p),
+                                _pad_u32(dst, p))
+            # the answer and the probe's counters: one transfer
+            return np.asarray(sp.fetch(found))[:len(src)]
 
     def neighbors(self, vertices, *, out_capacity: int = 4096
                   ) -> EdgeFrontier:

@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.distributed.sharded_graph import shard_mesh
 from repro.stream import GraphStore, ShardedGraphStore
 
@@ -62,9 +63,12 @@ for ep in range(4):
            else np.zeros((0, 2), np.uint32))
     dels = (cur[rng.choice(len(cur), min(30, len(cur)), replace=False)]
             if len(cur) else np.zeros((0, 2), np.uint32))
+    if ep == 3:
+        obs.trace.enable()               # the last epoch counts its probes
     bv = sv.apply(ins[:, 0], ins[:, 1], None, dels[:, 0], dels[:, 1])
     bm = sm.apply(ins[:, 0], ins[:, 1], None, dels[:, 0], dels[:, 1])
     bu = us.apply(ins[:, 0], ins[:, 1], None, dels[:, 0], dels[:, 1])
+    obs.trace.disable()
     assert bv.n_inserted == bm.n_inserted == bu.n_inserted, \
         (ep, bv.n_inserted, bm.n_inserted, bu.n_inserted)
     assert bv.n_deleted == bm.n_deleted == bu.n_deleted
@@ -77,6 +81,19 @@ for ep in range(4):
                           us.query(q[:, 0], q[:, 1])), ep
 print("OK mixed epochs: shard_map pools leaf-for-leaf == vmap pools; "
       "queries track unsharded store")
+
+# both renderings walk the same chains: the same hops and visits per probe
+# loop (widths differ where the shard_map plane compacts a bucket)
+cv, cm, cu = (
+    {k: v for k, v in c.items() if k.startswith("probe.")}
+    for c in obs.trace.span_counters()["store.apply.dispatch"])
+assert set(cv) == set(cm) == set(cu) and len(cv) == 7 * 3, sorted(cv)
+for k in cv:
+    if not k.endswith(".width"):
+        assert cv[k] == cm[k], (k, cv[k], cm[k])
+    assert cv[k.rsplit(".", 1)[0] + ".visits"] <= \
+        cv[k.rsplit(".", 1)[0] + ".hops"] * cv[k.rsplit(".", 1)[0] + ".width"]
+print("OK probe counters: shard_map hops/visits == vmap hops/visits")
 print("recompiles: vmap", sv.recompile_count,
       "shard_map", sm.recompile_count)
 

@@ -12,8 +12,14 @@ Coverage planes:
   maintenance pass: instrumentation only reads clocks and blocks on
   already-computed results, never changes a value;
 * zero-overhead-when-off — the disabled fast path stays within a
-  generous constant factor of un-instrumented dispatch.
+  generous constant factor of un-instrumented dispatch;
+* counters that ride spans (``obs.count``) and the device-side counts
+  behind them — probe hops/visits against a host walk of the chains,
+  WCC hooking rounds against a Python replay of the union, PageRank
+  iterations — read in one transfer per span, and toggling tracing
+  recompiles nothing.
 """
+import gc
 import json
 
 import numpy as np
@@ -201,11 +207,6 @@ class TestTimedDispatch:
         assert out == 2                          # no block on tracers
         assert obs.kernel_stats() == {}          # and no bogus timing
 
-    def test_pool_bytes_counts_array_leaves(self):
-        tree = {"a": jnp.zeros((4, 8), jnp.float32), "b": 3,
-                "c": [jnp.zeros((2,), jnp.int32)]}
-        assert obs.pool_bytes(tree) == 4 * 8 * 4 + 2 * 4
-
     def test_kernel_entry_points_record(self):
         from repro.stream import GraphStore
         rng = np.random.default_rng(0)
@@ -290,6 +291,10 @@ class TestNeutrality:
     def test_graph_store_pools_identical_on_vs_off(self):
         off = self._drive_graph_store(False)
         on = self._drive_graph_store(True)
+        # the traced run read the device counters back, the other did not
+        counted = obs.trace.span_counters()
+        assert counted["store.apply.dispatch"] and counted["store.query"]
+        assert counted["pagerank.solve"]
         assert len(off) == len(on)
         for a, b in zip(off, on):
             assert a.dtype == b.dtype and a.shape == b.shape
@@ -343,9 +348,398 @@ class TestNoopOverhead:
         assert med(wrapped) < 30 * med(bare) + 0.05
 
     def test_disabled_span_and_helpers_cost_nothing_observable(self):
-        with obs.span("x", a=1):
-            pass
+        with obs.span("x", a=1) as sp:
+            obs.count("x", 1)
+            obs.count("x", jnp.int32(2))
+            assert sp.sync_on(jnp.zeros(2)) is sp
         obs.instant("x")
         obs.observe("x", 1.0)
         assert obs.trace.events() == []
+        assert obs.trace.span_counters() == {}
         assert obs.get_registry().counters() == {}
+
+    def test_disabled_count_overhead_bounded(self):
+        import time
+
+        def bare(name, value):
+            return None
+
+        def med(fn):
+            ts = []
+            for _ in range(7):
+                t0 = time.perf_counter()
+                for _ in range(20000):
+                    fn("x", 1)
+                ts.append(time.perf_counter() - t0)
+            ts.sort()
+            return ts[len(ts) // 2]
+
+        med(bare), med(obs.count)                # warmup
+        assert med(obs.count) < 30 * med(bare) + 0.05
+
+
+# ============================================================================
+# counters that ride spans
+# ============================================================================
+
+def _closed(name):
+    return [e for e in obs.trace.events()
+            if e["ph"] == "E" and e["name"] == name]
+
+
+class TestCounters:
+    def test_count_rides_the_innermost_span(self):
+        obs.trace.enable()
+        with obs.span("outer"):
+            obs.count("a", 1)
+            with obs.span("inner", tag=3):
+                obs.count("a", 2)
+                obs.count("a", jnp.int32(5))
+                obs.count("b", 0.5)
+            obs.count("a", 10)
+        obs.count("orphan", 1)                   # no open span: dropped
+        assert _closed("inner")[0]["args"] == {"tag": 3, "a": 7, "b": 0.5}
+        assert _closed("outer")[0]["args"] == {"a": 11}
+        assert obs.trace.span_counters() == {
+            "inner": [{"a": 7, "b": 0.5}], "outer": [{"a": 11}]}
+
+    def test_counters_outlive_reset_until_the_next_session(self):
+        obs.trace.enable()
+        with obs.span("s"):
+            obs.count("a", 1)
+        obs.trace.disable()
+        obs.trace.reset()
+        assert obs.trace.events() == []
+        assert obs.trace.span_counters() == {"s": [{"a": 1}]}
+        obs.trace.enable()
+        assert obs.trace.span_counters() == {}
+
+    def test_device_counters_read_once_per_span(self, monkeypatch):
+        calls = []
+        real = jax.device_get
+
+        def counting(tree):
+            calls.append(1)
+            return real(tree)
+
+        monkeypatch.setattr(jax, "device_get", counting)
+        obs.trace.enable()
+        with obs.span("s"):
+            obs.count("a", jnp.int32(1))
+            obs.count("b", jnp.int32(2))
+        assert len(calls) == 1
+        calls.clear()
+        x, four = jnp.arange(3), jnp.int32(4)
+        with obs.span("t") as sp:                # the span's own read
+            obs.count("a", four)
+            got = sp.fetch(x)
+        assert len(calls) == 1 and list(got) == [0, 1, 2]
+        assert _closed("t")[0]["args"] == {"a": 4}
+
+    def test_store_reads_answers_and_counters_in_one_transfer(
+            self, monkeypatch):
+        """Tracing adds no device-to-host transfer to a store call: the
+        counters ride the read the call makes anyway."""
+        from repro.stream import GraphStore
+        rng = np.random.default_rng(2)
+        src = rng.integers(0, 60, 300).astype(np.uint32)
+        dst = rng.integers(0, 60, 300).astype(np.uint32)
+        store = GraphStore.from_edges(60, src, dst)
+        calls = []
+        real = jax.device_get
+
+        def counting(tree):
+            calls.append(1)
+            return real(tree)
+
+        def transfers(traced):
+            (obs.trace.enable if traced else obs.trace.disable)()
+            calls.clear()
+            store.apply(ins_src=rng.integers(0, 60, 5),
+                        ins_dst=rng.integers(0, 60, 5),
+                        del_src=src[:3], del_dst=dst[:3])
+            store.query(src[:9], dst[:9])
+            return len(calls)
+
+        transfers(False)                         # compile every shape
+        monkeypatch.setattr(jax, "device_get", counting)
+        assert transfers(False) == transfers(True) == 2
+        counted = obs.trace.span_counters()
+        assert counted["store.apply.dispatch"] and counted["store.query"]
+
+    def test_sync_on_ends_the_span_after_the_work(self):
+        obs.trace.enable()
+        x = jnp.arange(1 << 12, dtype=jnp.float32)
+        with obs.span("s") as sp:
+            y = jnp.sin(x) * 2
+            sp.sync_on(y)
+        assert sp.sync is y
+        assert y.is_ready()
+
+    def test_hooks_live_only_while_tracing_is_on(self):
+        from repro.obs import trace
+        assert trace._on_gc not in gc.callbacks
+        obs.trace.enable()
+        obs.trace.enable()                       # idempotent
+        assert gc.callbacks.count(trace._on_gc) == 1
+        with obs.span("work"):
+            jax.jit(lambda v: v * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+            gc.collect()
+        obs.trace.disable()
+        assert trace._on_gc not in gc.callbacks
+        work = _closed("work")[0]["args"]
+        assert work["jit.jaxpr_trace_s"] > 0
+        assert work["jit.backend_compile_s"] > 0
+        gcs = _closed("host.gc")
+        assert gcs and gcs[-1]["args"]["generation"] == 2
+        n = len(obs.trace.events())
+        gc.collect()                             # off: nothing recorded
+        assert len(obs.trace.events()) == n
+
+
+# ============================================================================
+# device-side counts against host references
+# ============================================================================
+
+def _hub_edges():
+    """Vertex 0 owns one bucket with a 6-slab chain; vertices 1..40 one
+    short slab each (no hashing: one bucket per vertex)."""
+    hub = np.arange(1, 1 + 6 * 128 - 5)
+    src = np.concatenate([np.zeros(len(hub)), np.arange(1, 41)])
+    dst = np.concatenate([hub, np.arange(2, 42)])
+    return src.astype(np.uint32), dst.astype(np.uint32)
+
+
+def _hub_graph():
+    from repro.core import from_edges_host
+    return from_edges_host(64, *_hub_edges(), hashing=False)
+
+
+def _host_walk(g, src, dst):
+    """Per query lane, the slabs its chain walk visits (to its hit or the
+    chain's end; 0 for a rejected lane)."""
+    from repro.kernels.slab_update.ref import batch_valid, edge_buckets
+    sj, dj = jnp.asarray(src, jnp.uint32), jnp.asarray(dst, jnp.uint32)
+    valid = np.asarray(batch_valid(g, sj, dj))
+    head = np.asarray(edge_buckets(g, sj, dj, jnp.asarray(valid)))
+    keys, nxt = np.asarray(g.keys), np.asarray(g.next_slab)
+    out = []
+    for v, cur, d in zip(valid, head, np.asarray(dst, np.uint32)):
+        n = 0
+        while v and cur != -1:
+            n += 1
+            if (keys[cur] == d).any():
+                break
+            cur = nxt[cur]
+        out.append(n)
+    return np.array(out)
+
+
+class TestProbeCounts:
+    # lane 0 finds the hub's last edge, lane 1 misses on the hub's whole
+    # chain, the rest hit or miss one-slab vertices, the pad is rejected
+    SRC = [0, 0] + list(range(1, 29)) + [2, 0xFFFFFFFF]
+    DST = [6 * 128 - 5, 9999] + list(range(2, 30)) + [7, 1]
+
+    def test_probe_matches_a_host_walk(self):
+        from repro.kernels.slab_update import probe_counted
+        from repro.kernels.slab_update.ref import batch_valid, edge_buckets
+        g = _hub_graph()
+        sj = jnp.asarray(self.SRC, jnp.uint32)
+        dj = jnp.asarray(self.DST, jnp.uint32)
+        valid = batch_valid(g, sj, dj)
+        found, _, _, pc = probe_counted(g, edge_buckets(g, sj, dj, valid),
+                                        dj, valid)
+        walk = _host_walk(g, self.SRC, self.DST)
+        assert walk[0] == walk[1] == 6 and walk.max() == 6
+        assert int(pc.hops) == walk.max()
+        assert int(pc.visits) == walk.sum()
+        assert pc.width == len(self.SRC)
+        assert bool(found[0]) and not bool(found[1])
+
+    def test_store_query_span_carries_the_walk(self):
+        from repro.core import next_pow2
+        from repro.stream import GraphStore
+        store = GraphStore.from_edges(64, *_hub_edges(),
+                                      with_transpose=False,
+                                      with_symmetric=False)
+        store.query(self.SRC[:-1], self.DST[:-1])      # compile first
+        obs.trace.enable()
+        got = store.query(self.SRC[:-1], self.DST[:-1])
+        walk = _host_walk(store.forward, self.SRC[:-1], self.DST[:-1])
+        (c,) = obs.trace.span_counters()["store.query"]
+        assert c["probe.query.hops"] == walk.max()
+        assert c["probe.query.visits"] == walk.sum()
+        # the batch is padded up the store's power-of-two ladder
+        assert c["probe.query.width"] == next_pow2(len(self.SRC) - 1)
+        assert got[0] and not got[1]
+
+    def test_dispatch_span_counts_every_probe_loop(self):
+        from repro.stream import GraphStore
+        rng = np.random.default_rng(3)
+        src = rng.integers(0, 80, 600).astype(np.uint32)
+        dst = rng.integers(0, 80, 600).astype(np.uint32)
+        store = GraphStore.from_edges(80, src, dst)
+        obs.trace.enable()
+        store.apply(ins_src=[1, 2, 3], ins_dst=[4, 5, 6],
+                    del_src=src[:5], del_dst=dst[:5])
+        (c,) = obs.trace.span_counters()["store.apply.dispatch"]
+        loops = {k.rsplit(".", 1)[0] for k in c if k.startswith("probe.")}
+        assert loops == {f"probe.{r}.{op}" for r in
+                         ("forward", "transpose", "symmetric")
+                         for op in ("delete", "insert")} | {
+            "probe.symmetric.reverse"}
+        for loop in loops:
+            hops, visits, width = (c[f"{loop}.{k}"]
+                                   for k in ("hops", "visits", "width"))
+            assert 1 <= hops and 0 < visits <= hops * width
+        assert c["probe.symmetric.insert.width"] == \
+            2 * c["probe.forward.insert.width"]
+
+    def test_sharded_dispatch_counts_over_its_shards(self):
+        from repro.stream import ShardedGraphStore
+        rng = np.random.default_rng(4)
+        src = rng.integers(0, 90, 700).astype(np.uint32)
+        dst = rng.integers(0, 90, 700).astype(np.uint32)
+        store = ShardedGraphStore.from_edges(90, 4, src, dst,
+                                             dispatch="vmap")
+        obs.trace.enable()
+        store.apply(ins_src=[1, 2, 3], ins_dst=[4, 5, 6],
+                    del_src=src[:6], del_dst=dst[:6])
+        (c,) = obs.trace.span_counters()["store.apply.dispatch"]
+        loops = {k.rsplit(".", 1)[0] for k in c if k.startswith("probe.")}
+        assert "probe.forward.delete" in loops
+        assert "probe.forward.insert" in loops
+        for loop in loops:
+            hops, visits, width = (c[f"{loop}.{k}"]
+                                   for k in ("hops", "visits", "width"))
+            assert width % 4 == 0                # S shards x their batch
+            assert 1 <= hops and 0 < visits <= hops * width
+
+
+def _py_union_rounds(parent, u, v, mask):
+    """A Python replay of ``union_batch``: hooking rounds until no edge
+    joins two roots."""
+    def compress(p):
+        while (p != p[p]).any():
+            p = p[p]
+        return p
+
+    parent = compress(parent.copy())
+    active = mask & (parent[u] != parent[v])
+    rounds = 0
+    while active.any():
+        ru, rv = parent[u], parent[v]
+        diff = mask & (ru != rv) & active
+        np.minimum.at(parent, np.maximum(ru, rv)[diff],
+                      np.minimum(ru, rv)[diff])
+        parent = compress(parent)
+        active = mask & (parent[u] != parent[v])
+        rounds += 1
+    return parent, rounds
+
+
+class TestAnalyticCounts:
+    def test_hook_rounds_match_a_python_union(self):
+        from repro.core.union_find import init_parents, union_batch_counted
+        rng = np.random.default_rng(5)
+        n = 200
+        # a shuffled path plus random chords: several rounds of hooking
+        u = np.concatenate([np.arange(n - 1), rng.integers(0, n, 40)])
+        v = np.concatenate([np.arange(1, n), rng.integers(0, n, 40)])
+        order = rng.permutation(len(u))
+        u, v = u[order].astype(np.int32), v[order].astype(np.int32)
+        mask = np.ones(len(u), bool)
+        mask[::7] = False
+        want_parent, want = _py_union_rounds(np.arange(n, dtype=np.int32),
+                                             u, v, mask)
+        parent, rounds = union_batch_counted(init_parents(n), u, v, mask)
+        assert want >= 2 and int(rounds) == want
+        assert np.array_equal(np.asarray(parent), want_parent)
+
+    def test_wcc_refresh_span_carries_the_hook_rounds(self):
+        from repro.algorithms import wcc_stream_property
+        from repro.algorithms.wcc import wcc_static_counted
+        from repro.stream import GraphStore, PropertyRegistry
+        rng = np.random.default_rng(6)
+        src = rng.integers(0, 150, 400).astype(np.uint32)
+        dst = rng.integers(0, 150, 400).astype(np.uint32)
+        store = GraphStore.from_edges(150, src, dst)
+        reg = PropertyRegistry(store)
+        reg.register(wcc_stream_property())
+        store.apply(del_src=src[:4], del_dst=dst[:4])
+        obs.trace.enable()
+        labels = reg.read("wcc")                 # a deleting epoch: refresh
+        store.apply(ins_src=[1], ins_dst=[2])
+        reg.read("wcc")                          # insert only: incremental
+        obs.trace.disable()
+        want_labels, want = wcc_static_counted(store.forward)
+        (c,) = obs.trace.span_counters()["wcc.refresh"]
+        assert c["wcc.hook_rounds"] == int(want)
+        names = [e["name"] for e in obs.trace.events() if e["ph"] == "E"]
+        assert names.count("wcc.incremental") == 1
+        assert labels.shape == want_labels.shape
+
+    def test_pagerank_span_carries_the_solver_iterations(self):
+        from repro.algorithms import pagerank, pagerank_stream_property
+        from repro.stream import GraphStore, PropertyRegistry
+        rng = np.random.default_rng(7)
+        src = rng.integers(0, 120, 500).astype(np.uint32)
+        dst = rng.integers(0, 120, 500).astype(np.uint32)
+        store = GraphStore.from_edges(120, src, dst)
+        reg = PropertyRegistry(store)
+        reg.register(pagerank_stream_property())
+        prev = reg.read("pagerank")
+        store.apply(ins_src=[3, 4], ins_dst=[5, 6])
+        obs.trace.enable()
+        pr = reg.read("pagerank")
+        obs.trace.disable()
+        want_pr, want = pagerank(store.transpose, store.out_degree,
+                                 init_pr=prev, rows=store.sweep_rows())
+        (c,) = obs.trace.span_counters()["pagerank.solve"]
+        assert c["pagerank.iters"] == int(want) > 1
+        assert np.array_equal(np.asarray(pr), np.asarray(want_pr))
+
+
+class TestTracingCompilesNothing:
+    def test_toggling_tracing_between_calls_compiles_nothing(self):
+        from repro.algorithms import (pagerank_stream_property,
+                                      wcc_stream_property)
+        from repro.stream import GraphStore, PropertyRegistry
+        compiles = []
+
+        def listen(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        rng = np.random.default_rng(9)
+        V = 160
+        src = rng.integers(0, V, 700).astype(np.uint32)
+        dst = rng.integers(0, V, 700).astype(np.uint32)
+        store = GraphStore.from_edges(V, src, dst)
+        reg = PropertyRegistry(store)
+        reg.register(pagerank_stream_property())
+        reg.register(wcc_stream_property())
+
+        def step(k):
+            # same shapes every call: 4 inserts, one query of 8 pairs
+            store.apply(ins_src=rng.integers(0, V, 4),
+                        ins_dst=rng.integers(0, V, 4))
+            store.query(rng.integers(0, V, 8), rng.integers(0, V, 8))
+            jax.block_until_ready((reg.read("pagerank"), reg.read("wcc")))
+
+        for k in range(3):                       # warm every shape
+            step(k)
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            for k in range(4):
+                if k % 2:
+                    obs.trace.enable()
+                else:
+                    obs.trace.disable()
+                step(k)
+        finally:
+            obs.trace.disable()
+            jax.monitoring.unregister_event_duration_listener(listen)
+        assert compiles == []
+        assert obs.trace.span_counters()["store.apply.dispatch"]
