@@ -2,7 +2,7 @@
 
 The paper's headline wins over Hornet are on the *update* plane (12.94×
 insert, 6.1× delete, 12.6× query) — this module makes batched mutation a
-first-class fused engine instead of a chain of generic XLA ops.  Three
+first-class fused engine instead of a chain of generic XLA ops.  Four
 things distinguish the engine from the ``ref.py`` oracle it reproduces
 bit-for-bit:
 
@@ -27,6 +27,11 @@ bit-for-bit:
    ``use_commit_kernel=True``: its per-lane loop serializes within a grid
    step, so the default commit is the vectorized XLA scatter — already
    in-place under donation — until a tiled commit lowering proves faster.
+
+4. **Packed probe** (``probe_packed``, the XLA engine's chain walk): as a
+   batch's lanes find their keys or reach their chains' ends, the lanes
+   still walking are packed into loops half as wide, so one long hub chain
+   no longer makes every lane of the batch gather a row per trip.
 
 Implementation selection (``impl``):
 
@@ -57,7 +62,7 @@ from ...obs import timed_dispatch
 from .. import resolve_impl
 from .kernel import slab_commit_pallas, slab_probe_pallas
 from .ref import (ProbeCount, batch_valid, delete_edges_ref, edge_buckets,
-                  insert_edges_ref, probe_counted, query_edges_ref)
+                  insert_edges_ref, query_edges_ref)
 
 IMPLS = ("auto", "pallas", "jnp", "oracle")
 
@@ -70,10 +75,10 @@ _STATIC = ("impl", "interpret", "queries_per_tile", "use_commit_kernel")
 
 
 def count_probes(probes) -> None:
-    """``probe.<loop>.{hops,visits,width}`` counters on the innermost open
-    ``obs`` span, from a dict of ``ProbeCount`` (None: the impl counts
-    nothing); a no-op with tracing off.  The device values ride the span's
-    one read at its exit (or its ``fetch``)."""
+    """``probe.<loop>.{hops,visits,width,rows,packs}`` counters on the
+    innermost open ``obs`` span, from a dict of ``ProbeCount`` (None: the
+    impl counts nothing); a no-op with tracing off.  The device values ride
+    the span's one read at its exit (or its ``fetch``)."""
     if not obs.trace.enabled():
         return
     for key, pc in probes.items():
@@ -81,6 +86,8 @@ def count_probes(probes) -> None:
             obs.count(f"probe.{key}.hops", pc.hops)
             obs.count(f"probe.{key}.visits", pc.visits)
             obs.count(f"probe.{key}.width", pc.width)
+            obs.count(f"probe.{key}.rows", pc.rows)
+            obs.count(f"probe.{key}.packs", pc.packs)
 
 
 def _copy_aliased(tree):
@@ -110,6 +117,96 @@ def _resolve(impl: str, interpret: Optional[bool]):
                         impls=("pallas", "jnp", "oracle"))
 
 
+#: the narrowest stage of ``probe_packed``: below it a trip's fixed cost
+#: outweighs the rows it gathers
+PACK_FLOOR = 128
+
+
+def _stage_widths(B: int) -> Tuple[int, ...]:
+    """``probe_packed``'s stage widths: B, B/2, B/4, ... down to
+    ``PACK_FLOOR`` lanes; one stage for a batch of ``PACK_FLOOR`` or
+    fewer."""
+    widths = [B]
+    while widths[-1] // 2 >= PACK_FLOOR:
+        widths.append(widths[-1] // 2)
+    return tuple(widths)
+
+
+def probe_packed(g: SlabGraph, bucket, dst, valid):
+    """``ref.probe_counted``'s (found, slab, lane) and ``ProbeCount``, with
+    finished lanes retired from the loop.
+
+    Stage k walks ``w_k`` lanes (``_stage_widths``) until none is live or
+    at most ``w_{k+1}`` are, then packs the live ones into the first
+    ``w_{k+1}`` slots (a prefix sum of the live mask places them) and
+    hands them to stage k+1.  A pack, and every later stage, is skipped
+    once no lane is live.  Each lane's walk is the oracle's, so every
+    result and ``hops``/``visits`` equal its; ``rows`` counts the
+    ``w_k`` rows each trip of stage k gathers."""
+    B = bucket.shape[0]
+    widths = _stage_widths(B)
+    dst = dst.astype(jnp.uint32)
+    cur = jnp.where(valid, bucket, INVALID_SLAB).astype(jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def stage(k, idx, cur, dst, n, out, tally):
+        # idx: each slot's batch position (B for an empty slot; None at
+        # stage 0, where slot and position coincide)
+        w = widths[k]
+        last = k + 1 == len(widths)
+        stop = 0 if last else widths[k + 1]
+
+        def trip(state):
+            cur, slab, lane, trips, visits, n = state
+            live = cur != INVALID_SLAB
+            rows = g.keys[jnp.maximum(cur, 0)]                 # (w, 128)
+            hit = (rows == dst[:, None]) & live[:, None]
+            hit_any = jnp.any(hit, axis=1)
+            slab = jnp.where(hit_any, cur, slab)
+            lane = jnp.where(hit_any,
+                             jnp.argmax(hit, axis=1).astype(jnp.int32), lane)
+            nxt = g.next_slab[jnp.maximum(cur, 0)]
+            cur = jnp.where(live & ~hit_any, nxt, INVALID_SLAB)
+            return (cur, slab, lane, trips + 1, visits + n,
+                    jnp.sum((cur != INVALID_SLAB).astype(jnp.int32)))
+
+        cur, slab, lane, trips, visits, n = jax.lax.while_loop(
+            lambda s: s[-1] > stop, trip,
+            (cur, jnp.full((w,), INVALID_SLAB, jnp.int32),
+             jnp.full((w,), -1, jnp.int32), zero, zero, n))
+        if idx is None:
+            out = (slab, lane)
+        else:
+            out = (out[0].at[idx].set(slab, mode="drop"),
+                   out[1].at[idx].set(lane, mode="drop"))
+        hops, vis, rows, packs = tally
+        tally = (hops + trips, vis + visits, rows + w * trips, packs)
+        if last:
+            return out, tally
+
+        def pack():
+            w2 = widths[k + 1]
+            live = cur != INVALID_SLAB
+            pos = jnp.cumsum(live.astype(jnp.int32)) - 1
+            src = jnp.full((w2,), w, jnp.int32).at[
+                jnp.where(live, pos, w2)].set(
+                    jnp.arange(w, dtype=jnp.int32), mode="drop")
+            used = src < w
+            src = jnp.minimum(src, w - 1)
+            at = src if idx is None else idx[src]
+            return stage(k + 1, jnp.where(used, at, B),
+                         jnp.where(used, cur[src], INVALID_SLAB), dst[src],
+                         n, out, tally[:3] + (tally[3] + 1,))
+
+        return jax.lax.cond(n > 0, pack, lambda: (out, tally))
+
+    n0 = jnp.sum((cur != INVALID_SLAB).astype(jnp.int32))
+    (slab, lane), (hops, visits, rows, packs) = stage(
+        0, None, cur, dst, n0, None, (zero, zero, zero, zero))
+    return slab != INVALID_SLAB, slab, lane, ProbeCount(
+        hops=hops, visits=visits, width=B, rows=rows, packs=packs)
+
+
 def _probe_dispatch(g, bucket, dst, valid, *, impl, interpret, qpt):
     """(found, slab, lane, ProbeCount | None): the Pallas probe reports no
     count."""
@@ -118,7 +215,7 @@ def _probe_dispatch(g, bucket, dst, valid, *, impl, interpret, qpt):
         return (*slab_probe_pallas(g.keys, g.next_slab, start, dst,
                                    queries_per_tile=qpt,
                                    interpret=interpret), None)
-    return probe_counted(g, bucket, dst, valid)
+    return probe_packed(g, bucket, dst, valid)
 
 
 def _classify(g, src, dst, *, impl, interpret, qpt):
@@ -661,6 +758,7 @@ def update_views(views: Tuple[SlabGraph, ...], roles: Tuple[str, ...],
 
 
 __all__ = ["IMPLS", "FORWARD", "TRANSPOSE", "SYMMETRIC", "ProbeCount",
+           "probe_packed",
            "query_edges", "insert_edges", "delete_edges",
            "apply_update", "update_views", "count_probes",
            "update_shards", "query_shards",

@@ -60,16 +60,22 @@ def edge_buckets(g: SlabGraph, src: jnp.ndarray, dst: jnp.ndarray,
 
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=("hops", "visits"), meta_fields=("width",))
+                   data_fields=("hops", "visits", "rows", "packs"),
+                   meta_fields=("width",))
 @dataclasses.dataclass(frozen=True)
 class ProbeCount:
-    """The work of one probe loop: ``hops`` trips of its ``while_loop``,
-    ``visits`` the lanes still walking a chain summed over the trips, and
-    ``width`` the (static) batch width, so ``visits / (hops * width)`` is
-    the share of gathered rows that belonged to a live query."""
+    """The work of one probe loop: ``hops`` trips of its ``while_loop``s,
+    ``visits`` the lanes still walking a chain summed over the trips,
+    ``width`` the (static) batch width, ``rows`` the slab rows actually
+    gathered and ``packs`` the times the loop packed its live lanes into
+    a narrower one.  ``visits / rows`` is the share of gathered rows that
+    belonged to a live query; ``visits / (hops * width)`` is what it
+    would be if every trip gathered the whole batch."""
     hops: jnp.ndarray
     visits: jnp.ndarray
     width: int
+    rows: jnp.ndarray
+    packs: jnp.ndarray
 
 
 def probe_counted(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
@@ -79,8 +85,11 @@ def probe_counted(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
 
     The inner body is the warp-cooperative slab probe: one gathered slab row
     (128 lanes) per query per hop, lane-wide equality, ``ballot``→``any``.
-    Whole-batch termination — every lane waits on the longest chain; the
-    Pallas kernel (``kernel.slab_probe_pallas``) terminates per tile instead.
+    Whole-batch termination — every lane waits on the longest chain, so
+    every trip gathers the whole batch (``rows == hops * width``).  This is
+    the oracle's loop; the XLA engine (``ops.probe_packed``) packs the
+    lanes still walking into narrower loops, and the Pallas kernel
+    (``kernel.slab_probe_pallas``) terminates per tile.
     """
     B = bucket.shape[0]
     cur = jnp.where(valid, bucket, INVALID_SLAB).astype(jnp.int32)
@@ -114,7 +123,9 @@ def probe_counted(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,
     _, found, slab, lane, hops, steps = jax.lax.while_loop(
         cond, body, (cur, found, slab, lane, jnp.zeros((), jnp.int32), steps))
     visits = jnp.sum(steps)
-    return found, slab, lane, ProbeCount(hops, visits, B)
+    return found, slab, lane, ProbeCount(
+        hops=hops, visits=visits, width=B, rows=hops * B,
+        packs=jnp.zeros((), jnp.int32))
 
 
 def probe(g: SlabGraph, bucket: jnp.ndarray, dst: jnp.ndarray,

@@ -85,11 +85,13 @@ def _across_shards(pc: Optional[ProbeCount], n_shards: int
                    ) -> Optional[ProbeCount]:
     """One probe loop's count over every shard, from per-shard (S,) counts
     of ``pc.width`` lanes each: the loop ends with its slowest shard, so
-    ``hops`` is the most any shard made, over all S x width lanes."""
+    ``hops`` is the most any shard made, over all S x width lanes; ``rows``
+    sums the shards' gathers and ``packs`` is the most any shard made."""
     if pc is None:
         return None
-    return ProbeCount(jnp.max(pc.hops), jnp.sum(pc.visits),
-                      n_shards * pc.width)
+    return ProbeCount(hops=jnp.max(pc.hops), visits=jnp.sum(pc.visits),
+                      width=n_shards * pc.width, rows=jnp.sum(pc.rows),
+                      packs=jnp.max(pc.packs))
 
 
 def _sharded_apply_body(views, ins, dels, *, roles, n_shards, caps,
@@ -236,11 +238,12 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
         counts = {}
 
         def note(key, out):
-            # the shard's (hops, visits) leave as (1,) rows of an (S,) array
+            # the shard's counts leave as (1,) rows of (S,) arrays
             *rest, pc = out
             if pc is not None:
                 widths[key] = pc.width
-                counts[key] = (pc.hops[None], pc.visits[None])
+                counts[key] = (pc.hops[None], pc.visits[None],
+                               pc.rows[None], pc.packs[None])
             return rest[0] if len(rest) == 1 else tuple(rest)
 
         def route(s, d, w, cap):
@@ -374,8 +377,9 @@ def _sharded_apply_sm(views, dels, ins, *, roles, n_shards, caps, mesh,
     # each batch position is owned by exactly one shard: OR the partials
     ins_mask = None if ins_parts is None else ins_parts.any(axis=0)
     del_mask = None if del_parts is None else del_parts.any(axis=0)
-    probes = {k: _across_shards(ProbeCount(h, v, widths[k]), n_shards)
-              for k, (h, v) in counts.items()}
+    probes = {k: _across_shards(ProbeCount(hops=h, visits=v, width=widths[k],
+                                           rows=r, packs=p), n_shards)
+              for k, (h, v, r, p) in counts.items()}
     return out_views, ins_mask, del_mask, probes
 
 

@@ -82,18 +82,73 @@ for ep in range(4):
 print("OK mixed epochs: shard_map pools leaf-for-leaf == vmap pools; "
       "queries track unsharded store")
 
-# both renderings walk the same chains: the same hops and visits per probe
-# loop (widths differ where the shard_map plane compacts a bucket)
-cv, cm, cu = (
-    {k: v for k, v in c.items() if k.startswith("probe.")}
-    for c in obs.trace.span_counters()["store.apply.dispatch"])
-assert set(cv) == set(cm) == set(cu) and len(cv) == 7 * 3, sorted(cv)
-for k in cv:
-    if not k.endswith(".width"):
-        assert cv[k] == cm[k], (k, cv[k], cm[k])
-    assert cv[k.rsplit(".", 1)[0] + ".visits"] <= \
-        cv[k.rsplit(".", 1)[0] + ".hops"] * cv[k.rsplit(".", 1)[0] + ".width"]
+
+def probe_loops(c):
+    """loop -> its counters, from one span's ``probe.<loop>.<k>``."""
+    loops = {}
+    for k, v in c.items():
+        if k.startswith("probe."):
+            loop, field = k.rsplit(".", 1)
+            loops.setdefault(loop, {})[field] = v
+    return loops
+
+
+def check_counts(cv, cm):
+    """Both renderings walk the same chains: the same hops and visits per
+    probe loop.  Widths, and so the rows gathered and the packs, differ
+    only where the shard_map plane compacts a bucket."""
+    assert set(cv) == set(cm), (sorted(cv), sorted(cm))
+    for loop in cv:
+        v, m = cv[loop], cm[loop]
+        assert set(v) == set(m) == {"hops", "visits", "width", "rows",
+                                    "packs"}, sorted(v)
+        assert (v["hops"], v["visits"]) == (m["hops"], m["visits"]), loop
+        if v["width"] == m["width"]:
+            assert (v["rows"], v["packs"]) == (m["rows"], m["packs"]), loop
+        for c in (v, m):
+            assert c["visits"] <= c["rows"] <= c["hops"] * c["width"], loop
+
+
+cv, cm, cu = (probe_loops(c) for c in
+              obs.trace.span_counters()["store.apply.dispatch"])
+assert set(cv) == set(cu) and len(cv) == 7, sorted(cv)
+check_counts(cv, cm)
 print("OK probe counters: shard_map hops/visits == vmap hops/visits")
+
+# a hub whose chain outlasts the rest of a wide batch: the probe loops pack
+# their live lanes on every shard that holds it, inside shard_map as under
+# vmap, and the pools stay leaf-for-leaf identical
+VH, HUB = 4096, 5
+hsrc = np.concatenate([np.full(1500, HUB), rng.integers(0, VH, 3000)])
+hdst = np.concatenate([rng.choice(np.arange(HUB + 1, VH), 1500,
+                                  replace=False), rng.integers(0, VH, 3000)])
+keep = hsrc != hdst
+hsrc, hdst = hsrc[keep].astype(np.uint32), hdst[keep].astype(np.uint32)
+hv = ShardedGraphStore.from_edges(VH, S, hsrc, hdst, dispatch="vmap")
+hm = ShardedGraphStore.from_edges(VH, S, hsrc, hdst).place_on_mesh(mesh)
+hu = GraphStore.from_edges(VH, hsrc, hdst)
+ins = np.stack([np.where(np.arange(2048) % 4 == 0, HUB,
+                         rng.integers(0, VH, 2048)),
+                rng.integers(0, VH, 2048)], 1).astype(np.uint32)
+ins = ins[ins[:, 0] != ins[:, 1]]
+q = np.concatenate([ins[:1024], np.stack([hsrc[:1024], hdst[:1024]], 1)])
+obs.trace.enable()
+hv.apply(ins[:, 0], ins[:, 1])
+hm.apply(ins[:, 0], ins[:, 1])
+obs.trace.disable()
+for name in hv.views:
+    assert leaves_equal(hv.views[name], hm.views[name]), name
+assert np.array_equal(hm.query(q[:, 0], q[:, 1]), hv.query(q[:, 0], q[:, 1]))
+hu.apply(ins[:, 0], ins[:, 1])
+assert np.array_equal(hm.query(q[:, 0], q[:, 1]), hu.query(q[:, 0], q[:, 1]))
+cv, cm = (probe_loops(c) for c in
+          obs.trace.span_counters()["store.apply.dispatch"][-2:])
+check_counts(cv, cm)
+for c in (cv, cm):
+    ins_loop = c["probe.forward.insert"]
+    assert ins_loop["packs"] >= 1, ins_loop
+    assert ins_loop["rows"] < ins_loop["hops"] * ins_loop["width"], ins_loop
+print("OK hub epoch: packed probe loops, shard_map pools == vmap pools")
 print("recompiles: vmap", sv.recompile_count,
       "shard_map", sm.recompile_count)
 

@@ -541,20 +541,25 @@ class TestProbeCounts:
     SRC = [0, 0] + list(range(1, 29)) + [2, 0xFFFFFFFF]
     DST = [6 * 128 - 5, 9999] + list(range(2, 30)) + [7, 1]
 
-    def test_probe_matches_a_host_walk(self):
+    @pytest.mark.parametrize("loop", ["oracle", "packed"])
+    def test_probe_matches_a_host_walk(self, loop):
+        g = _hub_graph()                 # repro.core before the kernels
         from repro.kernels.slab_update import probe_counted
+        from repro.kernels.slab_update.ops import probe_packed
         from repro.kernels.slab_update.ref import batch_valid, edge_buckets
-        g = _hub_graph()
+        probe = {"oracle": probe_counted, "packed": probe_packed}[loop]
         sj = jnp.asarray(self.SRC, jnp.uint32)
         dj = jnp.asarray(self.DST, jnp.uint32)
         valid = batch_valid(g, sj, dj)
-        found, _, _, pc = probe_counted(g, edge_buckets(g, sj, dj, valid),
-                                        dj, valid)
+        found, _, _, pc = probe(g, edge_buckets(g, sj, dj, valid), dj, valid)
         walk = _host_walk(g, self.SRC, self.DST)
         assert walk[0] == walk[1] == 6 and walk.max() == 6
         assert int(pc.hops) == walk.max()
         assert int(pc.visits) == walk.sum()
         assert pc.width == len(self.SRC)
+        # a batch under the packing floor walks as one whole-batch loop
+        assert int(pc.rows) == walk.max() * len(self.SRC)
+        assert int(pc.packs) == 0
         assert bool(found[0]) and not bool(found[1])
 
     def test_store_query_span_carries_the_walk(self):
@@ -572,6 +577,8 @@ class TestProbeCounts:
         assert c["probe.query.visits"] == walk.sum()
         # the batch is padded up the store's power-of-two ladder
         assert c["probe.query.width"] == next_pow2(len(self.SRC) - 1)
+        assert c["probe.query.rows"] == walk.max() * c["probe.query.width"]
+        assert c["probe.query.packs"] == 0
         assert got[0] and not got[1]
 
     def test_dispatch_span_counts_every_probe_loop(self):
@@ -590,9 +597,11 @@ class TestProbeCounts:
                          for op in ("delete", "insert")} | {
             "probe.symmetric.reverse"}
         for loop in loops:
-            hops, visits, width = (c[f"{loop}.{k}"]
-                                   for k in ("hops", "visits", "width"))
-            assert 1 <= hops and 0 < visits <= hops * width
+            hops, visits, width, rows, packs = (
+                c[f"{loop}.{k}"]
+                for k in ("hops", "visits", "width", "rows", "packs"))
+            assert 1 <= hops and 0 < visits <= rows <= hops * width
+            assert packs >= 0
         assert c["probe.symmetric.insert.width"] == \
             2 * c["probe.forward.insert.width"]
 
@@ -611,10 +620,12 @@ class TestProbeCounts:
         assert "probe.forward.delete" in loops
         assert "probe.forward.insert" in loops
         for loop in loops:
-            hops, visits, width = (c[f"{loop}.{k}"]
-                                   for k in ("hops", "visits", "width"))
+            hops, visits, width, rows, packs = (
+                c[f"{loop}.{k}"]
+                for k in ("hops", "visits", "width", "rows", "packs"))
             assert width % 4 == 0                # S shards x their batch
-            assert 1 <= hops and 0 < visits <= hops * width
+            assert 1 <= hops and 0 < visits <= rows <= hops * width
+            assert packs >= 0
 
 
 def _py_union_rounds(parent, u, v, mask):

@@ -131,6 +131,79 @@ def test_engine_overflow_chains_bit_identical(impl):
 
 
 # ---------------------------------------------------------------------------
+# the engine's packed probe vs the oracle's whole-batch loop
+# ---------------------------------------------------------------------------
+
+HUB_SLABS = 40
+
+
+def _hub_pool():
+    """Vertex 0 owns a 40-slab chain (a hub); vertices 1..599 one slab
+    each (no hashing: one bucket per vertex)."""
+    n = HUB_SLABS * SLAB_WIDTH
+    src = np.concatenate([np.zeros(n), np.arange(1, 600)])
+    dst = np.concatenate([np.arange(1000, 1000 + n), np.arange(2, 601)])
+    return from_edges_host(8192, src.astype(np.uint32),
+                           dst.astype(np.uint32), hashing=False)
+
+
+def _probe_batch(case, rng):
+    """(src, dst) of one probe batch, padded with INVALID lanes."""
+    if case == "invalid":
+        return pad([], 512), pad([], 512)
+    B = {"hub": 2048, "full": 512, "floor": 128, "trip1": 1024}[case]
+    src = rng.integers(1, 600, B)
+    dst = np.where(rng.random(B) < 0.5, src + 1, rng.integers(0, 8192, B))
+    if case in ("hub", "floor"):
+        # every 8th lane on the hub: present keys deep in its chain and
+        # absent ones that walk all of it
+        hub = np.arange(B) % 8 == 0
+        src[hub] = 0
+        dst[hub] = rng.integers(1000, 1000 + 2 * HUB_SLABS * SLAB_WIDTH,
+                                hub.sum())
+    if case == "full":
+        # half the lanes hit the hub past its first slab: after trip 1 the
+        # live lanes fill the next stage to its last slot
+        src[:B // 2] = 0
+        dst[:B // 2] = rng.integers(1000 + SLAB_WIDTH,
+                                    1000 + HUB_SLABS * SLAB_WIDTH, B // 2)
+    return pad(src[:B - 5], B), pad(dst[:B - 5], B)
+
+
+@pytest.mark.parametrize("case", ["hub", "full", "floor", "trip1",
+                                  "invalid"])
+def test_packed_probe_matches_whole_batch_oracle(case):
+    from repro.kernels.slab_update import probe_counted
+    from repro.kernels.slab_update.ops import PACK_FLOOR, probe_packed
+    from repro.kernels.slab_update.ref import batch_valid, edge_buckets
+    g = _hub_pool()
+    src, dst = _probe_batch(case, np.random.default_rng(7))
+    valid = batch_valid(g, src, dst)
+    b = edge_buckets(g, src, dst, valid)
+    *want, wc = jax.jit(probe_counted)(g, b, dst, valid)
+    *got, pc = jax.jit(probe_packed)(g, b, dst, valid)
+    for w, x in zip(want, got):                      # found, slab, lane
+        assert np.array_equal(np.asarray(w), np.asarray(x))
+    assert (int(pc.hops), int(pc.visits), pc.width) == \
+        (int(wc.hops), int(wc.visits), wc.width)
+    assert int(wc.rows) == int(wc.hops) * wc.width and int(wc.packs) == 0
+    hops, rows, packs = int(pc.hops), int(pc.rows), int(pc.packs)
+    if case in ("hub", "full"):
+        assert bool(np.asarray(got[0]).any())
+        assert packs >= 1 and rows < hops * pc.width
+    else:
+        assert packs == 0 and rows == hops * pc.width
+    if case == "hub":
+        assert hops == HUB_SLABS
+    if case == "floor":
+        assert pc.width <= PACK_FLOOR
+    if case == "trip1":
+        assert hops == 1
+    if case == "invalid":
+        assert hops == int(pc.visits) == 0
+
+
+# ---------------------------------------------------------------------------
 # engine vs host set-oracle property test (satellite: mixed epochs,
 # overflow chains, tombstones, deleted-then-reinserted pairs)
 # ---------------------------------------------------------------------------
