@@ -14,10 +14,14 @@ file found by its name:
 
 The served path is the program's public API, built as
 ``repro.launch.serve.build_service`` builds it: ``GraphStore.from_edges``
-(forward and transpose views, no hashing, a ``MaintenancePolicy``) ->
-``PropertyRegistry`` with the analytics the mix reads, lazily ->
-``RequestPipeline``.  The window is a closed loop with one client: each
-request is sent when the one before it has completed.
+(forward and transpose views, no hashing, a ``MaintenancePolicy``) or,
+where the configuration's ``store.shards`` is above 1,
+``ShardedGraphStore.from_edges`` (the same views, vertex-partitioned over
+a ``("shard",)`` mesh of the cell's chips, one shard each, every epoch one
+``shard_map`` program) -> ``PropertyRegistry`` with the analytics the mix
+reads, lazily (a single store only) -> ``RequestPipeline``.  The window is
+a closed loop with one client: each request is sent when the one before it
+has completed.
 """
 from __future__ import annotations
 
@@ -38,9 +42,17 @@ from . import reference, spans, trace_reduce
 from .generator import Graph, Rounds, arcs
 
 WARMUP_ROUNDS = 2
+# a sharded store sizes its routing buckets from each batch, with caps that
+# only ratchet up, and specializes its epoch program to them: its warm-up
+# goes on until this many rounds in a row meet no new program, or until
+# SHARDED_WARMUP_MAX rounds in all
+SHARDED_QUIET_ROUNDS = 20
+SHARDED_WARMUP_MAX = 80
 # rounds drawn ahead of the window, as a multiple of the rounds that the
 # warm-up's pace says the window will need
 DRAW_AHEAD = 1.5
+# spans of a traced window named in the log, most self time first
+SLOW_SPANS = 8
 # seconds of the window that the traced run also records with the profiler
 PROFILE_S = 3.0
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -111,7 +123,10 @@ class Run:
     #: per window round: ``t0``/``t1`` (host clock), ``edges`` (inserted
     #: plus deleted, as the store reported them, in edges: an undirected
     #: edge's two arcs count once) and ``requests``, each a dict with
-    #: ``kind``, ``name`` and blocked ``seconds``
+    #: ``kind``, ``name`` and blocked ``seconds``; in a traced run of a
+    #: sharded store an update also has ``counters``: the program's
+    #: ``obs.metrics`` counters once it completed, counted from the window's
+    #: start
     rounds: List[dict]
     #: the program's ``obs`` spans over the window (traced runs)
     spans: List[spans.Span] = dataclasses.field(default_factory=list)
@@ -133,20 +148,39 @@ def _most(traffic: dict, what: str) -> int:
                 if s["kind"] == "update"] or [0])
 
 
-def build_store(graph: Graph, config: dict, traffic: dict, src, dst):
+def shards_of(config: dict) -> int:
+    """The shards the configuration's store is partitioned into (1: one
+    ``GraphStore``)."""
+    return int(config["store"].get("shards", 1))
+
+
+def build_store(graph: Graph, config: dict, traffic: dict, src, dst,
+                devices=()):
     """The store over the edges ``src, dst`` (both arcs of each where the
-    graph is undirected)."""
-    from repro.stream import GraphStore, MaintenancePolicy
+    graph is undirected): a ``ShardedGraphStore`` with one shard on each of
+    ``devices`` where the configuration asks for shards, else a
+    ``GraphStore``."""
+    from repro.stream import GraphStore, MaintenancePolicy, ShardedGraphStore
 
     opts = config["store"]
     inserts = graph.arcs_per_edge * _most(traffic, "inserts")
     src, dst = arcs(src, dst, graph.directed)
-    return GraphStore.from_edges(
-        graph.n_vertices, src, dst, hashing=opts["hashing"],
+    maintenance = MaintenancePolicy(tombstone_ratio=opts["tombstone_ratio"])
+    shards = shards_of(config)
+    if shards == 1:
+        return GraphStore.from_edges(
+            graph.n_vertices, src, dst, hashing=opts["hashing"],
+            with_symmetric=opts["with_symmetric"],
+            slack_slabs=inserts // 64 + 512, maintenance=maintenance)
+    if shards != len(devices):
+        raise ValueError(f"{shards} shards need one chip each; the cell "
+                         f"has {len(devices)}")
+    from repro.distributed.sharded_graph import shard_mesh
+    return ShardedGraphStore.from_edges(
+        graph.n_vertices, shards, src, dst, with_transpose=True,
         with_symmetric=opts["with_symmetric"],
-        slack_slabs=inserts // 64 + 512,
-        maintenance=MaintenancePolicy(
-            tombstone_ratio=opts["tombstone_ratio"]))
+        slack_slabs=inserts // 64 + 512, maintenance=maintenance,
+        mesh=shard_mesh(shards))
 
 
 def build_service(store, graph: Graph, config: dict, traffic: dict):
@@ -157,9 +191,13 @@ def build_service(store, graph: Graph, config: dict, traffic: dict):
     from repro.stream import PropertyRegistry, RequestPipeline
 
     opts = config["store"]
-    registry = PropertyRegistry(store)
     reads = dict.fromkeys(s["name"] for s in traffic["round"]
                           if s["kind"] == "property")
+    if reads and shards_of(config) > 1:
+        # the single store's analytics would run on the stacked shards
+        raise ValueError(f"the harness serves no analytic {list(reads)} "
+                         f"of a sharded store yet")
+    registry = PropertyRegistry(store)
     for name in reads:
         if name == "pagerank":
             spec = pagerank_stream_property(
@@ -188,9 +226,12 @@ def _request(item: dict):
     return PropertyRead(item["name"])
 
 
-def serve_round(pipeline, rnd: dict, annotate, arcs_per_edge: int) -> dict:
+def serve_round(pipeline, rnd: dict, annotate, arcs_per_edge: int,
+                counters=None) -> dict:
     """Serve one round, one request at a time; each request is timed from
-    submission to a result the host holds or the device has finished."""
+    submission to a result the host holds or the device has finished.
+    ``counters``, where given, is read after each update and kept with
+    it."""
     import jax
     out = []
     t0 = time.perf_counter()
@@ -204,6 +245,8 @@ def serve_round(pipeline, rnd: dict, annotate, arcs_per_edge: int) -> dict:
             dt = time.perf_counter() - t
         out.append({"kind": item["kind"], "name": name, "seconds": dt,
                     "response": resp})
+        if counters is not None and item["kind"] == "update":
+            out[-1]["counters"] = counters()
     t1 = time.perf_counter()
     edges = sum(q["response"].payload.get("inserted", 0)
                 + q["response"].payload.get("deleted", 0)
@@ -216,16 +259,36 @@ def _views_ready(store) -> None:
     jax.block_until_ready(store.views)
 
 
+def _pool_rows(store) -> str:
+    """Each view's pool capacity in slab rows (per shard where sharded): a
+    change across the window means the pools grew and every program over
+    them met new shapes."""
+    return " ".join(f"{name} {getattr(g, 'graphs', g).keys.shape[-2]}"
+                    for name, g in store.views.items())
+
+
 def _store_keys(store, view: str, swap: bool) -> np.ndarray:
     """The view's live keys, sorted, read off its pool arrays: each lane
     holds a neighbour of its slab's owner; ``swap`` for the transpose view,
-    whose owner is the edge's destination."""
+    whose owner is the edge's destination.  A sharded view is read shard by
+    shard: shard ``k`` of ``S`` holds its owners as local ids, global
+    ``local * S + k``, and its neighbours as global ids."""
     import jax
     g = store.views[view]
-    keys, owner = jax.device_get((g.keys, g.slab_vertex))
-    rows, lanes = np.nonzero((owner >= 0)[:, None]
-                             & (keys < _FIRST_SENTINEL))
-    a, b = owner[rows], keys[rows, lanes]
+    stacked = getattr(g, "graphs", None)
+    if stacked is None:
+        parts = [jax.device_get((g.keys, g.slab_vertex))]
+        n = 1
+    else:
+        keys, owner = jax.device_get((stacked.keys, stacked.slab_vertex))
+        parts, n = list(zip(keys, owner)), g.n_shards
+    a, b = [], []
+    for k, (keys, owner) in enumerate(parts):
+        rows, lanes = np.nonzero((owner >= 0)[:, None]
+                                 & (keys < _FIRST_SENTINEL))
+        a.append(owner[rows].astype(np.int64) * n + k)
+        b.append(keys[rows, lanes])
+    a, b = np.concatenate(a), np.concatenate(b)
     return np.sort(reference.keys_of(b, a) if swap
                    else reference.keys_of(a, b))
 
@@ -314,7 +377,7 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
     lap(f"generate V={graph.n_vertices} E={len(src)}")
     rounds = Rounds(graph, traffic, src, dst)
     lap("ledger")
-    store = build_store(graph, config, traffic, src, dst)
+    store = build_store(graph, config, traffic, src, dst, devices)
     del src, dst
     _views_ready(store)
     lap("store build")
@@ -329,6 +392,14 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
     rounds.draw(WARMUP_ROUNDS)
     for rnd in rounds.rounds:
         served.append(serve_round(pipeline, rnd, untraced, per_edge))
+    quiet = 0
+    while shards_of(config) > 1 and quiet < SHARDED_QUIET_ROUNDS \
+            and len(served) < SHARDED_WARMUP_MAX:
+        met = compiles.compiles
+        rounds.draw(1)
+        served.append(serve_round(pipeline, rounds.rounds[-1], untraced,
+                                  per_edge))
+        quiet = quiet + 1 if compiles.compiles == met else 0
     # the pace of a round once its programs are compiled
     pace = min(r["t1"] - r["t0"] for r in served[1:])
     deletes = sum(int(s["deletes"]) for s in traffic["round"]
@@ -358,6 +429,13 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
     # -- the window -----------------------------------------------------------
     profile_dir = tempfile.mkdtemp(prefix="bench_profile_") if trace else None
     annotate = jax.profiler.TraceAnnotation if trace else untraced
+    # a sharded store counts each epoch's routing per shard in the
+    # program's metrics registry, which is kept only while it is on
+    counted = trace and shards_of(config) > 1
+    counters = obs.get_registry().counters if counted else None
+    if counted:
+        obs.get_registry().reset()
+        obs.metrics.enable()
     if trace:
         obs.trace.reset()
         obs.trace.enable()
@@ -365,17 +443,22 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
         traced = jax.profiler.TraceAnnotation(trace_reduce.WINDOW)
         traced.__enter__()
     n_compiles = compiles.n
+    rows_before = _pool_rows(store)
     t0 = time.perf_counter()
     setup_s = t0 - t_start
     deadline = t0 + seconds
-    window, drawn_in_window = [], 0.0
+    window, drawn_in_window, met = [], 0.0, []
     while True:
         if len(served) == len(rounds.rounds):
             t = time.perf_counter()
             rounds.draw(1)
             drawn_in_window += time.perf_counter() - t
+        before = compiles.compiles
         rec = serve_round(pipeline, rounds.rounds[len(served)],
-                          lambda kind: annotate(f"bench.{kind}"), per_edge)
+                          lambda kind: annotate(f"bench.{kind}"), per_edge,
+                          counters)
+        if compiles.compiles > before:
+            met.append(f"{len(window)}:{compiles.compiles - before}")
         served.append(rec)
         window.append(rec)
         if trace and traced is not None and \
@@ -389,6 +472,8 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
     _views_ready(store)
     t1 = time.perf_counter()
     in_window = compiles.n - n_compiles
+    if counted:
+        obs.metrics.disable()
     if trace:
         obs.trace.disable()
         if traced is not None:
@@ -398,6 +483,9 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
     log(f"window {t1 - t0:.3f} s, {len(window)} rounds (median "
         f"{paces[len(paces) // 2]:.3f} s, slowest {paces[-1]:.3f} s), "
         f"{in_window} compilations, {drawn_in_window:.3f} s drawing rounds")
+    log(f"window programs met anew (compiled or loaded from the cache), "
+        f"round:count: {' '.join(met) or 'none'}")
+    log(f"window pool rows {rows_before}; after {_pool_rows(store)}")
     for name in dict.fromkeys(q["name"] for q in window[0]["requests"]):
         times = sorted(q["seconds"] for r in window for q in r["requests"]
                        if q["name"] == name)
@@ -408,6 +496,10 @@ def _run(bench, workload, seed, seconds, trace, *, devices, t_start, log,
     if trace:
         run.spans = spans.from_events(obs.trace.events())
         obs.trace.reset()
+        slow = sorted(run.spans, key=lambda x: -x.self_ns)[:SLOW_SPANS]
+        log("window spans of most self time, self/whole ms: " + ", ".join(
+            f"{x.name} {x.self_ns / 1e6:.1f}/{x.ns / 1e6:.1f}"
+            for x in slow))
         pb = sorted(pathlib.Path(profile_dir).rglob("*.xplane.pb"))
         run.device = trace_reduce.reduce_file(pb[-1])
         shutil.rmtree(profile_dir, ignore_errors=True)

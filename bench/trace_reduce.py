@@ -10,7 +10,11 @@ each ``/device:TPU:<n>`` plane), clipped to the window and averaged over
 the devices.  An operation is named by its program (the ``XLA Modules``
 line) and its HLO name: ``jit__update_views_body/fusion.10``.  Each idle
 stretch is put down to the innermost ``bench.*`` annotation open at its
-midpoint.
+midpoint.  Collective time is the union of the intervals of the
+operations whose HLO name is a collective's (``all-to-all``,
+``all-gather``, ``all-reduce``, ``collective-permute``,
+``reduce-scatter``, with their ``-start``/``-done`` halves), reckoned as
+busy time is.
 
     python3 -m bench.trace_reduce <trace.xplane.pb>
 """
@@ -28,6 +32,9 @@ DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 TOP = 10
+#: HLO names of the collectives start with one of these
+COLLECTIVES = ("all-to-all", "all-gather", "all-reduce",
+               "collective-permute", "reduce-scatter")
 
 
 def _intervals(events) -> np.ndarray:
@@ -104,7 +111,7 @@ def reduce_planes(planes) -> Dict:
     if not devices:
         raise ValueError(f"trace has no {DEVICE_PREFIX}* plane")
     lo, hi = window
-    busy_ns, op_ns, gap_ns = [], {}, {}
+    busy_ns, coll_ns, op_ns, gap_ns = [], [], {}, {}
     notes.sort()
     starts = [n[0] for n in notes]
     for events in devices:
@@ -114,6 +121,10 @@ def reduce_planes(planes) -> Dict:
             op_ns[name] = op_ns.get(name, 0.0) + d
         busy = _union(_intervals(events), lo, hi)
         busy_ns.append(float((busy[:, 1] - busy[:, 0]).sum()))
+        coll = _union(_intervals(
+            [e for e in events
+             if e[2].rsplit("/", 1)[-1].startswith(COLLECTIVES)]), lo, hi)
+        coll_ns.append(float((coll[:, 1] - coll[:, 0]).sum()))
         for s, e in _gaps(busy, lo, hi):
             mid = (s + e) / 2
             what = "other"
@@ -127,7 +138,7 @@ def reduce_planes(planes) -> Dict:
     top = sorted(op_ns.items(), key=lambda kv: -kv[1])[:TOP]
     gaps = sorted(gap_ns.items(), key=lambda kv: -kv[1])[:TOP]
     return {"busy_s": sum(busy_ns) / n / 1e9, "window_s": (hi - lo) / 1e9,
-            "devices": n,
+            "devices": n, "collective_s": sum(coll_ns) / n / 1e9,
             "device_ops": [[k, v / n / 1e9] for k, v in top],
             "idle_gaps": [[k, v / n / 1e9] for k, v in gaps]}
 

@@ -45,13 +45,15 @@ def add_config(root: pathlib.Path, name: str, config: dict) -> None:
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
 
 
-def add_cell(root: pathlib.Path, config: str, traffic: str, like: str):
+def add_cell(root: pathlib.Path, config: str, traffic: str, like: str,
+             chips: int = 1):
     """List the cell ``config.traffic`` with the metrics of the cell
     ``like``."""
     spec = json.loads((root / "BENCHMARK.json").read_text())
     name = f"{config}.{traffic}"
     spec["workloads"].append({"name": name, "config": config,
-                              "traffic": traffic, "chips": 1, "why": "test"})
+                              "traffic": traffic, "chips": chips,
+                              "why": "test"})
     for m in spec["end_to_end"] + spec["per_layer"]:
         if like in m.get("workloads", ()):
             m["workloads"].append(name)
@@ -79,11 +81,12 @@ def make_root(dest: pathlib.Path) -> pathlib.Path:
 
 
 def run_tiny(root, workload: str, *, trace: bool = False,
-             seconds: float = 0.5, seed: int = 2 ** 33 + 17, control=None):
+             seconds: float = 0.5, seed: int = 2 ** 33 + 17, control=None,
+             chips: int = 1):
     import jax
 
     from bench import harness
     return harness.run_cell(
         harness.Bench(root), workload, seed, seconds, trace,
-        devices=jax.devices()[:1], t_start=time.perf_counter(),
+        devices=jax.devices()[:chips], t_start=time.perf_counter(),
         log=lambda msg: None, control=control)
